@@ -1,13 +1,16 @@
-"""ST benchmark worker (runs in its own process so it can claim fake
-devices). Originally Faces-only, now pattern-agnostic: ``--pattern``
-selects any registered ST program builder (faces / ring / a2a /
-broadcast / serve) and the whole worker body — build, schedule, execute,
-simulate, stats — is shared. Prints one CSV line: name,us_per_call,derived — plus a "#stats"
-comment line with the scheduled program's descriptor counts.
+"""ST benchmark worker (runs in its own process so it owns its devices:
+the fake host devices of CPU emulation under ``JAX_PLATFORMS=cpu``, or
+the chips it runs on). Originally Faces-only, now pattern-agnostic:
+``--pattern`` selects any registered ST program builder (faces / ring /
+a2a / broadcast / serve) and the whole worker body — build, schedule,
+execute, simulate, stats — is shared. Prints one CSV line:
+name,us_per_call,derived — plus a "#stats" comment line with the
+scheduled program's descriptor counts.
 
-  us_per_call — measured wall-clock per inner-loop iteration on this
-                CPU container (host-dispatch overheads are real; network
-                latencies are not).
+  us_per_call — measured wall-clock per inner-loop iteration on the
+                devices the worker ran on (under CPU emulation the
+                host-dispatch overheads are real; network latencies
+                are not).
   derived     — critical-path time from the calibrated schedule simulator
                 (core/throttle.py) walking the SAME scheduled descriptor
                 DAG the executor emits, with paper-like cost constants —
@@ -196,10 +199,26 @@ def main():
     ndev = 1
     for g in grid:
         ndev *= g
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={ndev}")
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        # CPU emulation: one fake host device per rank, added to (never
+        # replacing) whatever XLA flags the caller set
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+            os.environ.get("XLA_FLAGS", ""),
+            f"--xla_force_host_platform_device_count={ndev}")))
 
     import time
+
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    devices = jax.devices()
+    if len(devices) < ndev:
+        raise SystemExit(
+            f"grid {args.grid} needs {ndev} devices, but only "
+            f"{len(devices)} {devices[0].platform} device(s) are "
+            "available (set JAX_PLATFORMS=cpu to emulate the grid on "
+            "fake host devices)")
     from repro.core import STStream, get_pattern
     from repro.core.throttle import (CostModel, host_dispatch_count,
                                      simulate_pipeline)

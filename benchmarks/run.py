@@ -45,12 +45,16 @@ Sections:
          epoch cost per active-slot bucket — scheduled ST program vs
          the host-orchestrated baseline over the same epoch — executor
          workers over the serve pattern (host / adaptive ST / fused
-         progress engine with bit-identity), and an in-process
-         2-replica Poisson traffic smoke reporting p50/p99 latency,
-         TTFT, and tokens/sec (wall metrics in us_per_call,
+         progress engine with bit-identity), and a 2-replica
+         Poisson traffic smoke (in a child process) reporting p50/p99
+         latency, TTFT, and tokens/sec (wall metrics in us_per_call,
          derived=0.00 so container timing never gates the trajectory)
   roofline  per (arch x shape x mesh) terms from results/dryrun
   throughput  tiny-config train tokens/s
+
+The harness process itself never initialises a JAX backend: executor
+workers, the serving smoke and the throughput step each run in a child
+process, so each can own the devices it runs on.
 
 Worker failures are COUNTED and the harness exits nonzero (CI gates on
 this). ``--json PATH`` writes every parsed row + failures + invariant
@@ -84,6 +88,7 @@ bounded finite p99, serve-program meta present on every ST replica).
 keeps only the small-grid configs (CI), ``BENCH_NITER`` overrides
 iterations per worker.
 """
+import functools
 import json
 import os
 import subprocess
@@ -106,6 +111,37 @@ RESULTS = []       # parsed CSV rows across all sections
 FAILURES = []      # worker invocations that exited nonzero or hung
 
 
+def _spawn(section, cmd, args):
+    """Run one JAX-using child process and return its stdout, or None
+    after recording the failure. Everything that touches JAX runs in a
+    child: the harness parent never initialises a JAX backend, so each
+    child can own the devices (a chip belongs to one process at a
+    time)."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    try:
+        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                           timeout=2400)
+        returncode, stderr = r.returncode, r.stderr
+    except subprocess.TimeoutExpired as e:
+        r, returncode = None, -1
+        stderr = f"timeout after {e.timeout}s"
+    if returncode != 0:
+        print(f"# WORKER FAILED {args}: {stderr[-400:]}", flush=True)
+        FAILURES.append({"section": section,
+                         "args": {k: str(v) for k, v in args.items()},
+                         "returncode": returncode,
+                         "stderr": stderr[-400:]})
+        return None
+    return r.stdout
+
+
+def _child(section, body):
+    """Run ``body`` (a key of ``CHILD_BODIES``) as ``run.py --child``;
+    returns its stdout, or None after recording the failure."""
+    return _spawn(section, [sys.executable, os.path.abspath(__file__),
+                            "--child", body], {"child": body})
+
+
 def _worker(section="", **kw):
     kw.setdefault("niter", os.environ.get("BENCH_NITER", "10"))
     if env_flag("BENCH_VERIFY_STATIC"):
@@ -116,22 +152,10 @@ def _worker(section="", **kw):
     cmd = [sys.executable, WORKER]
     for k, v in kw.items():
         cmd += [f"--{k}", str(v)]
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
-    try:
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=2400)
-        returncode, stderr = r.returncode, r.stderr
-    except subprocess.TimeoutExpired as e:
-        r, returncode = None, -1
-        stderr = f"timeout after {e.timeout}s"
-    if returncode != 0:
-        print(f"# WORKER FAILED {kw}: {stderr[-400:]}", flush=True)
-        FAILURES.append({"section": section,
-                         "args": {k: str(v) for k, v in kw.items()},
-                         "returncode": returncode,
-                         "stderr": stderr[-400:]})
+    out = _spawn(section, cmd, kw)
+    if out is None:
         return False
-    for line in r.stdout.strip().splitlines():
+    for line in out.strip().splitlines():
         if line.startswith("#"):
             print(line, flush=True)
         elif "," in line:
@@ -744,7 +768,6 @@ _SERVE_GRID = (4,)
 _SERVE_RPN = 2
 _SERVE_BUCKETS = [2, 4]
 _SERVE_CACHE = None
-_SERVE_TRAFFIC_CACHE = None
 
 
 def _serve_points():
@@ -778,17 +801,22 @@ def _serve_points():
     return out
 
 
+@functools.cache
 def _serve_traffic():
-    """In-process serving smoke on the tiny reduced arch: the same
+    """The serving smoke's summary (:func:`_serve_traffic_body`, run in
+    a child process), or None when the child failed."""
+    out = _child("serve", "serve_traffic")
+    return None if out is None else json.loads(out.strip().splitlines()[-1])
+
+
+def _serve_traffic_body():
+    """Serving smoke on the tiny reduced arch: the same
     fixed-seed Poisson stream through a baseline fleet and an ST-routed
     fleet (2 replicas each, repro.launch.traffic), plus a fixed-request
     bit-identity comparison of the two decode paths on shared seeded
     params. Wall-clock only — the rows it feeds print derived=0.00 so
-    the trajectory gate never prices container timing."""
-    global _SERVE_TRAFFIC_CACHE
-    if _SERVE_TRAFFIC_CACHE is not None:
-        return _SERVE_TRAFFIC_CACHE
-    sys.path.insert(0, os.path.join(ROOT, "src"))
+    the trajectory gate never prices container timing. Prints the
+    summary as one JSON line."""
     import dataclasses
     import jax
     import numpy as np
@@ -830,8 +858,7 @@ def _serve_traffic():
 
     out["tokens_base"] = tokens(None)
     out["tokens_st"] = tokens("st")
-    _SERVE_TRAFFIC_CACHE = out
-    return out
+    print(json.dumps(out))
 
 
 def serve():
@@ -839,7 +866,7 @@ def serve():
     active-slot bucket (scheduled ST program vs the host-orchestrated
     baseline), executor workers over the serve pattern (host baseline,
     adaptive ST, fused progress engine with in-process bit-identity),
-    and the in-process 2-replica Poisson traffic smoke — p50/p99
+    and the 2-replica Poisson traffic smoke — p50/p99
     latency and TTFT rows carry wall time in us_per_call with
     derived=0.00."""
     print("# serve: decode-time collectives on scheduled ST programs "
@@ -865,6 +892,8 @@ def serve():
             nstreams=2, throttle="adaptive", merged=1, resources=8,
             verify_fused=1, name="serve_fused_4r")
     t = _serve_traffic()
+    if t is None:
+        return
     for mode in ("base", "st"):
         s = t[mode]
         for metric, val in (("lat_p50", s["latency_p50_ms"]),
@@ -904,8 +933,12 @@ def roofline():
 
 
 def throughput():
-    print("# throughput: tiny-config train on CPU (derived = tokens/s)")
-    sys.path.insert(0, os.path.join(ROOT, "src"))
+    out = _child("throughput", "throughput")
+    if out is not None:
+        print(out, end="", flush=True)
+
+
+def _throughput_body():
     import dataclasses
     import jax
     import jax.numpy as jnp
@@ -935,6 +968,8 @@ def throughput():
     jax.block_until_ready(m["loss"])
     dt = (time.perf_counter() - t0) / n
     toks = 8 * 128
+    print(f"# throughput: tiny-config train on {jax.devices()[0].platform}"
+          " (derived = tokens/s)")
     print(f"throughput_train_tiny,{dt*1e6:.0f},{toks/dt:.0f}")
 
 
@@ -1003,6 +1038,12 @@ def check_serve_invariants():
         print(f"# invariant serve b{p['bucket']}: st={p['st']:.2f} <= "
               f"host={p['host']:.2f} -> {'OK' if ok else 'VIOLATED'}")
     t = _serve_traffic()
+    if t is None:
+        checks.append(dict(rule="serve_bit_identity", pattern="serve",
+                           ok=False, requests=0))
+        print("# invariant serve bit-identity: traffic child failed -> "
+              "VIOLATED")
+        return checks
     ok = bool(t["tokens_base"]) and t["tokens_st"] == t["tokens_base"]
     checks.append(dict(rule="serve_bit_identity", pattern="serve",
                        ok=ok, requests=len(t["tokens_base"])))
@@ -1266,9 +1307,16 @@ SECTIONS = {
 }
 
 
+# section bodies that need JAX; each runs only in a child process
+CHILD_BODIES = {"serve_traffic": _serve_traffic_body,
+                "throughput": _throughput_body}
+
+
 def main() -> None:
     import argparse
     ap = argparse.ArgumentParser()
+    ap.add_argument("--child", choices=sorted(CHILD_BODIES),
+                    help=argparse.SUPPRESS)
     ap.add_argument("--only", default=None,
                     help="comma-separated section names")
     ap.add_argument("--json", default=None, metavar="PATH",
@@ -1282,6 +1330,9 @@ def main() -> None:
                     help="basename of the repo-root perf-trajectory "
                          "record --json also writes (env: BENCH_ID)")
     args = ap.parse_args()
+    if args.child:
+        CHILD_BODIES[args.child]()
+        return
     names = (args.only.split(",") if args.only else list(SECTIONS))
     print("name,us_per_call,derived")
     for n in names:

@@ -15,27 +15,6 @@ N = (4, 4, 4)
 NITER = 3
 
 
-def numpy_oracle(src0):
-    """src0: (8, nx,ny,nz) initial blocks. Replays NITER iterations."""
-    px, py, pz = GRID
-    src = src0.copy()
-    acc = None
-    for it in range(NITER):
-        src = src + np.float32(1.0 + it % 3)
-        acc = np.zeros_like(src)
-        for d in halo.DIRECTIONS:
-            for x in range(px):
-                for y in range(py):
-                    for z in range(pz):
-                        srank = (x * py + y) * pz + z
-                        tx, ty, tz = ((x + d[0]) % px, (y + d[1]) % py,
-                                      (z + d[2]) % pz)
-                        trank = (tx * py + ty) * pz + tz
-                        sl = halo.surface_slices(N, d)
-                        acc[(trank,) + sl] += src[(srank,) + sl]
-    return src, acc
-
-
 def run(mode, throttle="adaptive", merged=True):
     mesh = make_mesh(GRID, ("x", "y", "z"))
     stream = STStream(mesh, ("x", "y", "z"))
@@ -50,7 +29,7 @@ def run(mode, throttle="adaptive", merged=True):
         halo.enqueue_faces_iteration(stream, win, N, kernels, merged=merged)
     state = stream.synchronize(state, mode=mode, throttle=throttle,
                                resources=16, merged=merged, donate=False)
-    src_exp, acc_exp = numpy_oracle(src0)
+    src_exp, acc_exp = halo.faces_reference(src0, GRID, N, NITER)
     np.testing.assert_allclose(np.asarray(state["faces.src"]), src_exp,
                                rtol=1e-6)
     np.testing.assert_allclose(np.asarray(state["faces.acc"]), acc_exp,

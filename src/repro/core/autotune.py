@@ -145,18 +145,8 @@ def search_space(pattern: str, ranks_per_node: Optional[int] = None, *,
       * multicast only enumerated for the broadcast pattern (the only
         builder with the knob); elsewhere it stays None;
       * coalesce stays off — pack materializes the same aggregation as
-        real descriptors, which both executors honor;
-      * fused (the device-resident progress engine) enumerated only
-        when the installed JAX supports a fused emission path
-        (``compat.supports_fused`` — imported lazily so this module
-        stays jax-free); installations without it prune the knob
-        instead of erroring mid-search.
+        real descriptors, which both executors honor.
     """
-    try:
-        from repro.core.compat import supports_fused
-        fuseds = (False, True) if supports_fused() else (False,)
-    except Exception:           # noqa: BLE001 — no jax: prune the knob
-        fuseds = (False,)
     throttles = ("adaptive", "static")
     res = tuple(r for r in ((4, 8, 16) if full else (8, 16))
                 if r <= max_resources) or (max_resources,)
@@ -174,7 +164,7 @@ def search_space(pattern: str, ranks_per_node: Optional[int] = None, *,
                         for pk in bools:
                             for cb in chunks:
                                 for mc in mcasts:
-                                    for fu in fuseds:
+                                    for fu in (False, True):
                                         out.append(ScheduleConfig(
                                             throttle=throttle, resources=r,
                                             nstreams=ns, double_buffer=db,

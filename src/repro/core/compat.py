@@ -1,69 +1,24 @@
-"""Version-compatibility shims over the installed JAX.
+"""The two sharding entry points every executor and mesh builder shares.
 
-The repo targets the modern surface (``jax.shard_map``, ``check_vma``,
-``jax.sharding.AxisType``) but must run on older releases where
-``shard_map`` still lives in ``jax.experimental`` with the ``check_rep``
-spelling and ``AxisType`` does not exist. Feature-detect once at import;
-callers use these wrappers and never touch the moving targets directly.
+``shard_map`` fixes ``check_vma=False`` (the executors thread per-rank
+state whose varying-manual-axes typing the checker cannot follow);
+``make_mesh`` builds meshes with explicit ``Auto`` axis types so
+sharding propagation behaves the same on every caller's mesh.
 """
 from __future__ import annotations
 
 import jax
 
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the old ``check_rep`` kwarg papered over."""
-    if _NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
-
-
-def fusion_backend() -> str:
-    """Emission backend for the device-resident progress engine
-    (:mod:`repro.core.engine`): ``"pallas"`` when the default backend is
-    a TPU and Pallas imports (the arena counter-protocol can run as one
-    persistent ``pallas_call`` mega-kernel per segment), ``"traced"``
-    everywhere else (CPU emulation, GPU, missing Pallas) — the fused
-    wave-major traced emission, bit-identical by construction."""
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        return "traced"
-    if platform != "tpu":
-        return "traced"
-    try:
-        from jax.experimental import pallas  # noqa: F401
-    except ImportError:
-        return "traced"
-    return "pallas"
-
-
-def supports_fused() -> bool:
-    """Whether the installed JAX can run fused segments at all. Always
-    True today: the traced fallback needs nothing beyond what
-    ``run_compiled`` already uses — the autotuner gates the ``fused``
-    search-space knob on this so an installation that ever loses the
-    fallback prunes the knob instead of erroring mid-search."""
-    return True
+    """``jax.shard_map`` with ``check_vma`` off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with ``axis_types`` only where it exists."""
-    shape, axes = tuple(shape), tuple(axes)
-    mk = getattr(jax, "make_mesh", None)
-    if mk is None:
-        from jax.experimental import mesh_utils
-        return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return mk(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return mk(shape, axes)
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices, every
+    axis ``AxisType.Auto``."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

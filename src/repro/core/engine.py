@@ -23,24 +23,28 @@ scales with the SEGMENT count, not the descriptor count — the
 host-overhead win behind the paper's off-node P2P gap — and the
 simulator charges ``t_dispatch`` per segment accordingly.
 
-Emission backend selection (``compat.fusion_backend``):
+Emission backend selection (:func:`fusion_backend`, from the platform
+of the devices the stream's mesh spans; recorded per program in
+``prog.meta["fusion_backend"]``):
 
-  * ``"pallas"`` — TPU with Pallas available: the segment's
-    device-resident counter bumps run as ``pallas_call`` kernels
-    against the counter arena (the first rung of the mega-kernel
-    ladder; payload collectives stay traced ``ppermute`` — they must
-    cross ranks, which a single-core kernel cannot).
-  * ``"traced"`` — everywhere else (CPU emulation, GPU, no Pallas):
-    the fused units are traced wave-major (segment-contiguous) into ONE
+  * ``"pallas"`` — TPU: the segment's device-resident counter bumps
+    run as ``pallas_call`` kernels against the counter arena (the
+    first rung of the mega-kernel ladder; payload collectives stay
+    traced ``ppermute`` — they must cross ranks, which a single-core
+    kernel cannot).
+  * ``"traced"`` — CPU, the reference the tests compare against: the
+    fused units are traced wave-major (segment-contiguous) into ONE
     jitted shard_map launch. Bit-identical to ``run_compiled`` by
     construction: the same :func:`emit_node` emits every descriptor,
     dependency ties are value-neutral ``optimization_barrier`` edges,
     and all value-carrying effects thread through the state buffers.
     (Launching each segment as its OWN jit executable would change
     XLA's fusion context per boundary and perturb float reductions at
-    the ulp level — so the fallback keeps one executable and realizes
+    the ulp level — so the executor keeps one executable and realizes
     the per-segment structure in emission order, arena metadata, and
     the simulator's per-segment host-dispatch accounting.)
+
+Any other platform has no fused backend and raises.
 """
 from __future__ import annotations
 
@@ -49,8 +53,9 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
-from repro.core.compat import fusion_backend, shard_map
+from repro.core.compat import shard_map
 from repro.core.window import is_counter_name
 from repro.kernels.halo_pack.ref import (chunk_gather, chunk_scatter,
                                          pack_flat, unpack_flat)
@@ -64,14 +69,27 @@ def _tie(x, dep):
     return x
 
 
+def fusion_backend(mesh) -> str:
+    """Emission backend of the fused executor for a stream on ``mesh``,
+    decided by the platform of the devices the mesh spans: ``"pallas"``
+    on TPU, ``"traced"`` on CPU (the reference path). No other platform
+    has a fused backend."""
+    platform = mesh.devices.flat[0].platform
+    if platform == "tpu":
+        return "pallas"
+    if platform == "cpu":
+        return "traced"
+    raise NotImplementedError(
+        f"fused executor: no emission backend for platform {platform!r}")
+
+
 def _pallas_bump(sig, upd):
     """Device-resident counter bump as a Pallas kernel: the segment's
     merged post/completion counter update runs ON the device arena
     instead of as traced elementwise HLO. Only reached when
-    ``fusion_backend() == "pallas"`` (TPU); value-identical to
-    ``sig + upd`` — the engine's bit-identity guarantee does not depend
-    on which backend executed the bump."""
-    from jax.experimental import pallas as pl
+    :func:`fusion_backend` is ``"pallas"`` (TPU meshes); value-identical
+    to ``sig + upd`` — the engine's bit-identity guarantee does not
+    depend on which backend executed the bump."""
 
     def kernel(sig_ref, upd_ref, out_ref):
         out_ref[...] = sig_ref[...] + upd_ref[...]
@@ -317,28 +335,28 @@ def fused_order(prog, plan):
     return [by_id[oid] for seg in plan.segments for oid in seg.op_ids]
 
 
-def run_fused(stream, prog, state, donate=True):
-    """Execute a fused-scheduled program through the progress engine.
-
-    The planner's segments become the emission units: descriptors are
-    emitted wave-major (:func:`fused_order`), each segment's run traced
-    contiguously, with counter bumps routed through the backend
-    ``compat.fusion_backend`` selected (Pallas arena kernels on TPU,
-    plain traced HLO elsewhere). The traced fallback compiles ONE
-    executable for the whole program — the same launch shape as
-    ``run_compiled``, which is what makes the two executors bit-identical
-    on every pattern/knob combination — while the host-involvement model
-    (what the simulator charges and what the bench JSON reports) is
-    per SEGMENT: the device-resident counters sequence everything inside
-    a wave, and the host's only remaining job is advancing waves.
+def fused_executable(stream, prog, keys, donate=True):
+    """The jitted fused program for ``prog`` over the state ``keys``
+    (sorted), cached on the stream. The planner's segments become the
+    emission units: descriptors are emitted wave-major
+    (:func:`fused_order`), each segment's run traced contiguously, with
+    counter bumps routed through the backend :func:`fusion_backend`
+    picks for the stream's mesh; the choice is recorded in
+    ``prog.meta["fusion_backend"]``. ONE executable covers the whole
+    program — the same launch shape as ``run_compiled``, which is what
+    makes the two executors bit-identical on every pattern/knob
+    combination — while the host-involvement model (what the simulator
+    charges and what the bench JSON reports) is per SEGMENT: the
+    device-resident counters sequence everything inside a wave, and the
+    host's only remaining job is advancing waves.
 
     Programs scheduled without ``fused=True`` are planned lazily here."""
     plan = prog.meta.get("segment_plan")
     if plan is None:
         from repro.core.schedule import plan_segments
         plan = plan_segments(prog)
-    backend = fusion_backend()
-    keys = tuple(sorted(state.keys()))
+    backend = fusion_backend(stream.mesh)
+    prog.meta["fusion_backend"] = backend
     cache = getattr(stream, "_fused_cache", None)
     if cache is None:
         cache = stream._fused_cache = {}
@@ -361,5 +379,13 @@ def run_fused(stream, prog, state, donate=True):
         jfn = cache[ck] = jax.jit(
             sharded,
             donate_argnums=tuple(range(len(keys))) if donate else ())
-    out = jfn(*[state[k] for k in keys])
+    return jfn
+
+
+def run_fused(stream, prog, state, donate=True):
+    """Execute a fused-scheduled program through the progress engine
+    (:func:`fused_executable`); returns the new state."""
+    keys = tuple(sorted(state.keys()))
+    out = fused_executable(stream, prog, keys, donate)(
+        *[state[k] for k in keys])
     return dict(zip(keys, out))

@@ -140,6 +140,28 @@ def compare_kernel():
     return fn
 
 
+def faces_reference(src0, grid, n, niter):
+    """Plain numpy replay of ``niter`` Faces iterations on a periodic
+    ``grid`` — the oracle the executors are checked against. ``src0`` is
+    (num_ranks, *n) with ranks row-major over the grid (the linearization
+    of ``STStream.rank_strides``). Each iteration increments every block
+    by ``1 + it % 3``, then every rank sums the surface each of its 26
+    neighbours sends toward it into a fresh accumulator. Returns
+    (src, acc) after the last iteration, both (num_ranks, *n)."""
+    grid, n = tuple(grid), tuple(n)
+    src = np.asarray(src0, np.float32).reshape(grid + n)
+    acc = np.zeros_like(src)
+    for it in range(niter):
+        src = src + np.float32(1.0 + it % 3)
+        acc = np.zeros_like(src)
+        for d in DIRECTIONS:
+            # rank r receives what rank r - d sent in direction d
+            sl = (Ellipsis,) + surface_slices(n, d)
+            acc[sl] += np.roll(src, d, axis=(0, 1, 2))[sl]
+    num_ranks = int(np.prod(grid))
+    return src.reshape((num_ranks,) + n), acc.reshape((num_ranks,) + n)
+
+
 # ---------------------------------------------------------------------------
 # Program builders
 # ---------------------------------------------------------------------------

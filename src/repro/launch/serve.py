@@ -39,6 +39,9 @@ def main():
                     help="tuned-cache path for --st-config auto")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import get_config
     from repro.models import init_params, model_specs
     from repro.serving import Request, ServingEngine

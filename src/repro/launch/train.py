@@ -35,6 +35,9 @@ def main():
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import get_config
     from repro.data import SyntheticTokens, make_batch_iterator
     from repro.models import init_params, model_specs

@@ -12,6 +12,7 @@ path and the 512-device dry-run path.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -58,15 +59,17 @@ def _init_leaf(spec: ParamSpec, key, dtype) -> jax.Array:
 
 
 def init_params(spec_tree, rng, dtype=jnp.float32):
-    """Materialize params. Each leaf gets a key derived from its tree path,
-    so adding/removing params never reshuffles other inits."""
+    """Materialize params. Each leaf gets a key derived from its tree path
+    (a CRC32 digest, stable across processes, unlike the per-process
+    salted ``hash``), so adding/removing params never reshuffles other
+    inits and one seed gives the same weights in every process."""
     leaves_with_paths = jax.tree_util.tree_flatten_with_path(
         spec_tree, is_leaf=is_spec)[0]
     treedef = jax.tree.structure(spec_tree, is_leaf=is_spec)
     out = []
     for path, spec in leaves_with_paths:
         pstr = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(rng, abs(hash(pstr)) % (2**31))
+        key = jax.random.fold_in(rng, zlib.crc32(pstr.encode()) % (2**31))
         out.append(_init_leaf(spec, key, spec.dtype if dtype is None else dtype))
     return jax.tree.unflatten(treedef, out)
 

@@ -55,6 +55,7 @@ class _BucketEntry:
     state: dict
     config: Optional[ScheduleConfig]
     meta: dict
+    programs: list
     dispatches: int = 0
 
 
@@ -122,7 +123,7 @@ class STDecodeRouter:
                     ndev=self.ndev, moe=self.moe_on,
                     config=spec.label() if spec is not None else "default")
         e = _BucketEntry(stream=stream, win=win, state=state, config=spec,
-                         meta=meta)
+                         meta=meta, programs=progs)
         self._entries[bucket] = e
         return e
 
@@ -177,5 +178,7 @@ class STDecodeRouter:
     def stats(self) -> dict:
         return {"pattern": "serve", "mode": self.mode, "ndev": self.ndev,
                 "moe": self.moe_on,
-                "buckets": {b: dict(e.meta, dispatches=e.dispatches)
+                "buckets": {b: dict(e.meta, dispatches=e.dispatches,
+                                    fusion_backend=e.programs[0].meta.get(
+                                        "fusion_backend"))
                             for b, e in sorted(self._entries.items())}}

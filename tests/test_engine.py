@@ -23,13 +23,17 @@
     acyclic) with zero findings,
   * executor equivalence: the fused progress engine is bit-identical to
     run_compiled on every pattern — including packed, chunked, and
-    multicast descriptors (multi-device, in a subprocess).
+    multicast descriptors (multi-device, in a subprocess),
+  * emission backend: chosen from the platform of the mesh's devices
+    (Pallas on TPU, the traced reference on CPU, an error elsewhere).
 """
 import os
 import subprocess
 import sys
 import textwrap
+import types
 
+import numpy as np
 import pytest
 
 try:
@@ -39,6 +43,7 @@ except ImportError:                       # degrade to example-based sweeps
 
 from repro.core import (CostModel, fused_order, host_dispatch_count,
                         pattern_programs, plan_segments, simulate_pattern)
+from repro.core.engine import fusion_backend
 from repro.core.verify import verify
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -221,6 +226,22 @@ def test_verifier_clean_on_fused_schedules():
         assert rep.checked, pat
 
 
+@pytest.mark.parametrize("platform,backend", [("tpu", "pallas"),
+                                              ("cpu", "traced"),
+                                              ("gpu", None)])
+def test_fusion_backend_follows_mesh_platform(platform, backend):
+    """The backend comes from the devices the stream's mesh spans, never
+    the process's default backend, and no platform silently falls back
+    to the traced path."""
+    mesh = types.SimpleNamespace(
+        devices=np.array([types.SimpleNamespace(platform=platform)]))
+    if backend is None:
+        with pytest.raises(NotImplementedError, match=platform):
+            fusion_backend(mesh)
+    else:
+        assert fusion_backend(mesh) == backend
+
+
 # ---------------------------------------------------------------------------
 # executor equivalence: fused progress engine vs run_compiled,
 # bit-identical on every pattern incl. pack + chunk + multicast
@@ -276,6 +297,9 @@ EQUIV_SCRIPT = textwrap.dedent("""
                     sched_kw, throttle="adaptive", resources=8))
                 assert sum(p.meta.get("segments", 0) for p in progs), \\
                     (pat_name, "no segments — vacuous")
+                # a CPU mesh runs the traced reference emission
+                assert {p.meta["fusion_backend"] for p in progs} \\
+                    == {"traced"}, pat_name
             return {b: np.asarray(state[win.qual(b)]) for b in outputs}
 
         ref = run("st")

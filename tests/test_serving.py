@@ -197,6 +197,8 @@ def test_st_decode_bit_identical_to_baseline(tiny_model, mode):
         assert meta["pattern"] == "serve"
         if mode == "fused":
             assert meta["fused"] and meta["segments"] >= 1
+            # the router's mesh is on the CPU: the traced reference path
+            assert meta["fusion_backend"] == "traced"
 
 
 def test_st_schedule_cache_buckets(tiny_model):
