@@ -53,8 +53,9 @@ from repro.core.compat import shard_map
 # engine refactor; the names stay importable here for existing callers
 from repro.core.engine import (_arrival_mask, _emit_completion_signal,  # noqa: F401
                                _EmitCtx, _local_rank, _ppermute, _tie,
-                               emit_node)
+                               emit_node, launch)
 from repro.core.schedule import stream_interleaved_order
+from repro.runtime import spans
 
 
 # ---------------------------------------------------------------------------
@@ -62,13 +63,23 @@ from repro.core.schedule import stream_interleaved_order
 # ---------------------------------------------------------------------------
 
 def run_compiled(stream, prog, state, donate=True):
-    keys = tuple(sorted(state.keys()))
+    with spans.span("st.lookup"):
+        keys = tuple(sorted(state.keys()))
+        jfn, miss = _compiled_lookup(stream, prog, keys, donate)
+    out = launch(jfn, [state[k] for k in keys], miss)
+    return dict(zip(keys, out))
+
+
+def _compiled_lookup(stream, prog, keys, donate):
+    """The cached jitted program of ``prog`` over the state ``keys``, and
+    whether it had to be built."""
     cache = getattr(stream, "_compiled_cache", None)
     if cache is None:
         cache = stream._compiled_cache = {}
     ck = (prog.key(), keys, donate)
     jfn = cache.get(ck)
-    if jfn is None:
+    miss = jfn is None
+    if miss:
         spec = stream.state_spec()
         # multi-stream schedules trace in a stream-interleaved topological
         # order (program order within a stream; cross-stream ordering only
@@ -89,8 +100,7 @@ def run_compiled(stream, prog, state, donate=True):
         jfn = cache[ck] = jax.jit(
             sharded,
             donate_argnums=tuple(range(len(keys))) if donate else ())
-    out = jfn(*[state[k] for k in keys])
-    return dict(zip(keys, out))
+    return jfn, miss
 
 
 # ---------------------------------------------------------------------------
@@ -157,12 +167,22 @@ def run_host(stream, prog, state):
         if node.kind in _BLOCKING:
             # the host block the cost model charges t_sync for must
             # fence EVERY buffer of the state tree, not just one leaf
-            jax.block_until_ready(state)
+            with spans.span("st.block"):
+                jax.block_until_ready(state)
     return state
 
 
 def _dispatch_host(stream, node, state, unit):
-    keys = tuple(sorted(state.keys()))
+    with spans.span("st.lookup"):
+        keys = tuple(sorted(state.keys()))
+        jfn, miss = _host_lookup(stream, node, keys, unit)
+    out = launch(jfn, [state[k] for k in keys], miss)
+    return dict(zip(keys, out))
+
+
+def _host_lookup(stream, node, keys, unit):
+    """The cached one-descriptor executable of ``node``'s ``unit``, and
+    whether it had to be built."""
     cache = getattr(stream, "_host_cache", None)
     if cache is None:
         cache = stream._host_cache = {}
@@ -171,7 +191,8 @@ def _dispatch_host(stream, node, state, unit):
     # structural op serves all iterations
     ck = (unit, node.structural_key(with_deps=False), keys)
     jfn = cache.get(ck)
-    if jfn is None:
+    miss = jfn is None
+    if miss:
         spec = stream.state_spec()
 
         def one_fn(*vals):
@@ -194,5 +215,4 @@ def _dispatch_host(stream, node, state, unit):
             one_fn, mesh=stream.mesh,
             in_specs=(spec,) * len(keys), out_specs=(spec,) * len(keys))
         jfn = cache[ck] = jax.jit(sharded)
-    out = jfn(*[state[k] for k in keys])
-    return dict(zip(keys, out))
+    return jfn, miss

@@ -57,6 +57,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.compat import shard_map
 from repro.core.window import is_counter_name
+from repro.runtime import spans
 from repro.kernels.halo_pack.ref import (chunk_gather, chunk_scatter,
                                          pack_flat, unpack_flat)
 
@@ -187,12 +188,27 @@ def _emit_completion_signal(stream, node, st, arrival_token):
     return st
 
 
+def scope_name(node) -> str:
+    """``st.<kind>.<label>``: the name scope a descriptor's operations
+    carry in the HLO metadata (``op_name``), so a device trace can tell
+    the kernels and puts of a program apart. A ``/`` of the label (a
+    chunk's ``#c0/4``) would open a nested scope, and becomes ``_``."""
+    label = node.label.replace("/", "_").replace(" ", "")
+    return f"st.{node.kind}.{label}" if label else f"st.{node.kind}"
+
+
 def emit_node(stream, node, st, ctx, *, with_chained=True):
     """Apply one descriptor's state effect. Shared by every executor.
 
     Every node leaves a tiny effect token in ``ctx.tokens`` so dependency
     edges from ANY node kind (cross-stream conflict edges, throttle
-    edges) can be tied as dataflow."""
+    edges) can be tied as dataflow. The node's operations are emitted
+    under the name scope :func:`scope_name` gives (metadata only)."""
+    with jax.named_scope(scope_name(node)):
+        return _emit(stream, node, st, ctx, with_chained)
+
+
+def _emit(stream, node, st, ctx, with_chained):
     if node.kind == "kernel":
         args = [st[r] for r in node.reads]
         if args:
@@ -351,6 +367,11 @@ def fused_executable(stream, prog, keys, donate=True):
     host's only remaining job is advancing waves.
 
     Programs scheduled without ``fused=True`` are planned lazily here."""
+    return _fused_lookup(stream, prog, keys, donate)[0]
+
+
+def _fused_lookup(stream, prog, keys, donate):
+    """:func:`fused_executable`, and whether it had to be built."""
     plan = prog.meta.get("segment_plan")
     if plan is None:
         from repro.core.schedule import plan_segments
@@ -362,7 +383,8 @@ def fused_executable(stream, prog, keys, donate=True):
         cache = stream._fused_cache = {}
     ck = (prog.key(), keys, donate, backend)
     jfn = cache.get(ck)
-    if jfn is None:
+    miss = jfn is None
+    if miss:
         spec = stream.state_spec()
         order = fused_order(prog, plan)
 
@@ -379,13 +401,27 @@ def fused_executable(stream, prog, keys, donate=True):
         jfn = cache[ck] = jax.jit(
             sharded,
             donate_argnums=tuple(range(len(keys))) if donate else ())
-    return jfn
+    return jfn, miss
+
+
+def launch(jfn, args, miss):
+    """Call an executor's jitted function under the span ``st.launch``,
+    until it returns. The first call of a function the executor has just
+    built (``miss``) compiles it, and is recorded as ``st.compile``
+    instead."""
+    if miss:
+        with spans.span("st.compile"):
+            return jfn(*args)
+    with spans.span("st.launch"):
+        return jfn(*args)
 
 
 def run_fused(stream, prog, state, donate=True):
     """Execute a fused-scheduled program through the progress engine
-    (:func:`fused_executable`); returns the new state."""
-    keys = tuple(sorted(state.keys()))
-    out = fused_executable(stream, prog, keys, donate)(
-        *[state[k] for k in keys])
+    (:func:`fused_executable`); returns the new state. The cache key and
+    lookup are the span ``st.lookup``, the call :func:`launch`'s."""
+    with spans.span("st.lookup"):
+        keys = tuple(sorted(state.keys()))
+        jfn, miss = _fused_lookup(stream, prog, keys, donate)
+    out = launch(jfn, [state[k] for k in keys], miss)
     return dict(zip(keys, out))

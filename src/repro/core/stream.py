@@ -36,6 +36,16 @@ Throttling (paper §5.2) constrains put issue through a finite ResourcePool:
   * "adaptive": put i depends only on completion of put i-R (sliding window)
 These are schedule passes (dependency-edge transforms), not emission-time
 branches.
+
+Each ``synchronize`` is the program span ``st.synchronize``
+(:mod:`repro.runtime.spans`), holding ``st.schedule`` (the queue's
+cache key and the scheduled-program lookup, lowering on a miss), then
+per scheduled program the executor's ``st.lookup`` (its cache key and
+lookup), ``st.launch`` (the jitted call until it returns; ``st.compile``
+in its place for the first call of a newly built function) and
+``st.block`` (``block_until_ready``). The host executor records
+``st.lookup`` and ``st.launch`` per dispatch and ``st.block`` per epoch
+boundary.
 """
 from __future__ import annotations
 
@@ -53,6 +63,7 @@ from repro.core.lower import lower_segment, split_segments
 from repro.core.schedule import schedule
 from repro.core.triggered import TriggeredProgram
 from repro.core.window import STWindow
+from repro.runtime import spans
 
 
 @dataclass
@@ -309,22 +320,27 @@ class STStream:
             raise ValueError("cannot execute a device-free stream "
                              "(constructed with mesh=None)")
         fused = fused or mode == "fused"
-        for prog in self.scheduled_programs(
-                throttle=throttle, resources=resources, merged=merged,
-                ordered=ordered, nstreams=nstreams, node_aware=node_aware,
-                coalesce=coalesce, pack=pack, chunk_bytes=chunk_bytes,
-                fused=fused, config=config):
-            if mode == "fused":
-                from repro.core import engine
-                state = engine.run_fused(self, prog, state, donate=donate)
-            elif mode == "st":
-                state = backends.run_compiled(self, prog, state,
-                                              donate=donate)
-            else:
-                state = backends.run_host(self, prog, state)
-            # application-level sync between segments: a full host block
-            # must fence EVERY buffer, not just the first state leaf
-            jax.block_until_ready(state)
+        with spans.span("st.synchronize"):
+            with spans.span("st.schedule"):
+                progs = self.scheduled_programs(
+                    throttle=throttle, resources=resources, merged=merged,
+                    ordered=ordered, nstreams=nstreams,
+                    node_aware=node_aware, coalesce=coalesce, pack=pack,
+                    chunk_bytes=chunk_bytes, fused=fused, config=config)
+            for prog in progs:
+                if mode == "fused":
+                    from repro.core import engine
+                    state = engine.run_fused(self, prog, state,
+                                             donate=donate)
+                elif mode == "st":
+                    state = backends.run_compiled(self, prog, state,
+                                                  donate=donate)
+                else:
+                    state = backends.run_host(self, prog, state)
+                # application-level sync between segments: a full host
+                # block must fence EVERY buffer, not just the first leaf
+                with spans.span("st.block"):
+                    jax.block_until_ready(state)
         return state
 
 

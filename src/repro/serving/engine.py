@@ -20,9 +20,21 @@ transport (bit-identical to the baseline path by construction), program
 meta surfaced in :meth:`stats`. ``st_mode=None`` is the plain jitted
 baseline.
 
-Requests carry the traffic-driver timestamps: ``submitted_at`` (queue
-entry), ``admitted_at`` (prefill dispatch), ``first_token_at`` (TTFT),
-``done_at`` (completion).
+Requests carry timestamps for the traffic generators (``time.monotonic()``):
+``submitted_at`` (stamped by :meth:`ServingEngine.submit`),
+``dequeued_at`` (taken off the queue for admission), ``admitted_at``
+(its prefill returned), ``first_token_at`` (TTFT), ``done_at``
+(completion). The engine records its host work as program spans
+(:mod:`repro.runtime.spans`): ``engine.queued`` per request, keyed by
+its id, from submission to dequeue (added to the span ring at dequeue
+from the two stamps, so no profiler capture holds it); and
+``engine.step`` per step, keyed by the step number, holding
+``engine.admit`` (with ``engine.prefill`` per length group and
+``engine.cache_merge``), ``engine.decode`` (the launch),
+``engine.ids`` (the wait for the device and the ids' copy to the
+host), ``engine.extract`` (the ST payload's extract and readback) and
+``engine.commit`` (token bookkeeping); the router's ``router.dispatch``
+runs between the last two.
 """
 from __future__ import annotations
 
@@ -38,6 +50,7 @@ import numpy as np
 
 from repro.models import cache_specs
 from repro.models.params import is_spec
+from repro.runtime import spans
 from repro.train.steps import make_decode_sample_step, make_prefill_sample_step
 
 _req_ids = itertools.count()
@@ -50,7 +63,8 @@ class Request:
     eos_id: int = -1                    # -1: never stop early
     req_id: int = field(default_factory=lambda: next(_req_ids))
     out_tokens: List[int] = field(default_factory=list)
-    submitted_at: float = field(default_factory=time.monotonic)
+    submitted_at: Optional[float] = None
+    dequeued_at: Optional[float] = None
     admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     done_at: Optional[float] = None
@@ -82,6 +96,7 @@ class ServingEngine:
         self.completed: List[Request] = []
         self.prefill_dispatches = 0
         self.decode_steps = 0
+        self.steps = 0
         self.tokens_generated = 0
         self.st_mode = st_mode
         self._router = None
@@ -127,6 +142,7 @@ class ServingEngine:
 
     # -- admission ------------------------------------------------------------
     def submit(self, req: Request):
+        req.submitted_at = time.monotonic()
         self.queue.append(req)
 
     def _free_slots(self):
@@ -141,7 +157,14 @@ class ServingEngine:
             return
         take: List[Request] = []
         while self.queue and len(take) < len(free):
-            take.append(self.queue.popleft())
+            req = self.queue.popleft()
+            now_ns = time.monotonic_ns()
+            req.dequeued_at = now_ns / 1e9
+            # a request appended to ``queue`` directly was never stamped
+            if req.submitted_at is not None:
+                spans.record("engine.queued", round(req.submitted_at * 1e9),
+                             now_ns, req.req_id)
+            take.append(req)
         groups: Dict[int, List[Request]] = {}
         for req in take:
             groups.setdefault(len(req.prompt), []).append(req)
@@ -149,31 +172,35 @@ class ServingEngine:
         for L in sorted(groups):
             reqs = groups[L]
             slots = [next(free_iter) for _ in reqs]
-            toks = np.zeros((self.B, L), np.int32)
-            for slot, req in zip(slots, reqs):
-                toks[slot] = req.prompt
-            batch = {"tokens": jnp.asarray(toks),
-                     "positions": jnp.broadcast_to(
-                         jnp.arange(L, dtype=jnp.int32), (self.B, L))}
-            if self.cfg.family == "vlm":
-                batch["vision"] = jnp.zeros(
-                    (self.B, self.cfg.vision.num_tokens,
-                     self.cfg.vision.raw_dim), jnp.float32)
-            ids, new_cache = self._prefill_sample(self.params, batch)
-            self.prefill_dispatches += 1
+            with spans.span("engine.prefill",
+                            tuple(r.req_id for r in reqs)):
+                toks = np.zeros((self.B, L), np.int32)
+                for slot, req in zip(slots, reqs):
+                    toks[slot] = req.prompt
+                batch = {"tokens": jnp.asarray(toks),
+                         "positions": jnp.broadcast_to(
+                             jnp.arange(L, dtype=jnp.int32), (self.B, L))}
+                if self.cfg.family == "vlm":
+                    batch["vision"] = jnp.zeros(
+                        (self.B, self.cfg.vision.num_tokens,
+                         self.cfg.vision.raw_dim), jnp.float32)
+                ids, new_cache = self._prefill_sample(self.params, batch)
+                self.prefill_dispatches += 1
             # merge ONLY the group's cache rows (other slots keep
             # theirs). prefix-layer leaves are (B, ...); scanned-unit
             # leaves carry a leading layer axis (L, B, ...), so batch is
             # dim 1 there.
-            idx = jnp.asarray(np.array(slots, np.int32))
-            self.cache = {
-                "prefix": jax.tree.map(
-                    lambda old, new: old.at[idx].set(new[idx]),
-                    self.cache["prefix"], new_cache["prefix"]),
-                "unit": jax.tree.map(
-                    lambda old, new: old.at[:, idx].set(new[:, idx]),
-                    self.cache["unit"], new_cache["unit"]),
-            }
+            with spans.span("engine.cache_merge"):
+                idx = jnp.asarray(np.array(slots, np.int32))
+                self.cache = {
+                    "prefix": jax.tree.map(
+                        lambda old, new: old.at[idx].set(new[idx]),
+                        self.cache["prefix"], new_cache["prefix"]),
+                    "unit": jax.tree.map(
+                        lambda old, new: old.at[:, idx].set(new[:, idx]),
+                        self.cache["unit"], new_cache["unit"]),
+                }
+            # the wait for the prefill, in engine.admit's own time
             ids_np = np.asarray(ids)
             now = time.monotonic()
             for slot, req in zip(slots, reqs):
@@ -198,7 +225,13 @@ class ServingEngine:
 
     def step(self):
         """One engine step: admit, batched decode, recycle finished slots."""
-        self._admit()
+        self.steps += 1
+        with spans.span("engine.step", self.steps - 1):
+            return self._step()
+
+    def _step(self):
+        with spans.span("engine.admit"):
+            self._admit()
         active = self._active()
         if not active:
             return 0
@@ -212,35 +245,39 @@ class ServingEngine:
                 (self.B, self.cfg.vision.num_tokens,
                  self.cfg.vision.raw_dim), jnp.float32)
         pos_written = self.slot_pos.copy()      # rows this decode writes
-        ids, hid, self.cache = self._decode_sample(self.params, batch,
-                                                   self.cache)
+        with spans.span("engine.decode"):
+            ids, hid, self.cache = self._decode_sample(self.params, batch,
+                                                       self.cache)
         self.decode_steps += 1
-        ids_np = np.asarray(ids)
+        with spans.span("engine.ids"):
+            ids_np = np.asarray(ids)
         if self._router is not None:
             act = np.asarray(active, np.int32)
-            payload = np.asarray(
-                self._extract(self.cache, jnp.asarray(pos_written)))[act]
-            hid_np = (np.asarray(hid)[act]
-                      if self._router.moe_on else None)
+            with spans.span("engine.extract"):
+                payload = np.asarray(
+                    self._extract(self.cache, jnp.asarray(pos_written)))[act]
+                hid_np = (np.asarray(hid)[act]
+                          if self._router.moe_on else None)
             committed, _, _ = self._router.dispatch(payload, ids_np[act],
                                                     hid=hid_np)
             # the transported ids are authoritative: serving reads its
             # tokens off the committed window buffer
             ids_np = ids_np.copy()
             ids_np[act] = committed
-        for i in active:
-            req = self.slot_req[i]
-            nxt = int(ids_np[i])
-            req.out_tokens.append(nxt)
-            self.tokens_generated += 1
-            self.slot_pos[i] += 1
-            done = (len(req.out_tokens) >= req.max_new_tokens
-                    or nxt == req.eos_id
-                    or self.slot_pos[i] >= self.max_len - 1)
-            if done:
-                req.done_at = time.monotonic()
-                self.completed.append(req)
-                self.slot_req[i] = None
+        with spans.span("engine.commit"):
+            for i in active:
+                req = self.slot_req[i]
+                nxt = int(ids_np[i])
+                req.out_tokens.append(nxt)
+                self.tokens_generated += 1
+                self.slot_pos[i] += 1
+                done = (len(req.out_tokens) >= req.max_new_tokens
+                        or nxt == req.eos_id
+                        or self.slot_pos[i] >= self.max_len - 1)
+                if done:
+                    req.done_at = time.monotonic()
+                    self.completed.append(req)
+                    self.slot_req[i] = None
         return len(active)
 
     def run_until_drained(self, max_steps: int = 10_000):

@@ -29,6 +29,12 @@ transfers:
 counts, puts/epoch, segments, config label, dispatch count) — this is
 what surfaces in ``ServingEngine`` serving stats and the bench's
 serving table.
+
+Each dispatch is the program span ``router.dispatch``
+(:mod:`repro.runtime.spans`), holding ``router.stage`` (padding, the
+payloads' ``device_put``, the counter resets), the serve program's
+``st.synchronize`` and ``router.readback`` (the committed buffers'
+copies to the host).
 """
 from __future__ import annotations
 
@@ -43,6 +49,7 @@ from repro.core.autotune import ScheduleConfig, resolve_config, slot_bucket
 from repro.core.compat import make_mesh
 from repro.core.patterns import get_pattern
 from repro.core.stream import STStream
+from repro.runtime import spans
 
 _MODES = ("st", "host", "fused")
 
@@ -147,32 +154,40 @@ class STDecodeRouter:
         multi-rank grid). Returns ``(tok, mirror, hmir)`` read back
         from the COMMITTED window buffers, truncated to A rows (hmir is
         None without MoE dispatch)."""
-        A = int(np.asarray(tok_ids).shape[0])
-        bucket = slot_bucket(A, self.slot_cap)
-        e = self._entry(bucket)
-        self._stage(e, "kv", kv_rows, (bucket, self.kv_dim), self.dtype)
-        self._stage(e, "tok", tok_ids, (bucket,), np.int32)
-        if self.moe_on:
-            if hid is None:
-                raise ValueError("dispatch: hid payload required with moe")
-            self._stage(e, "hid", hid, (bucket, self.d_model), self.dtype)
-        # the persistent counters accumulate across dispatches; reset
-        # them so every epoch starts from the program's expected zeros
-        for cname in e.win.counter_names():
-            cur = e.state[cname]
-            e.state[cname] = jax.device_put(
-                jnp.zeros(cur.shape, cur.dtype), cur.sharding)
-        sync_kw = dict(mode=self.mode, donate=False)
-        if e.config is not None:
-            sync_kw["config"] = e.config
-        e.state = e.stream.synchronize(e.state, **sync_kw)
-        e.dispatches += 1
-        q = e.win.qual
-        tok = np.asarray(e.state[q("outtok")])[0, :A]
-        mirror = np.asarray(e.state[q("mirror")])[0, :A]
-        hmir = (np.asarray(e.state[q("hmir")])[0, :A]
-                if self.moe_on else None)
-        return tok, mirror, hmir
+        with spans.span("router.dispatch"):
+            with spans.span("router.stage"):
+                A = int(np.asarray(tok_ids).shape[0])
+                bucket = slot_bucket(A, self.slot_cap)
+                e = self._entry(bucket)
+                self._stage(e, "kv", kv_rows, (bucket, self.kv_dim),
+                            self.dtype)
+                self._stage(e, "tok", tok_ids, (bucket,), np.int32)
+                if self.moe_on:
+                    if hid is None:
+                        raise ValueError(
+                            "dispatch: hid payload required with moe")
+                    self._stage(e, "hid", hid, (bucket, self.d_model),
+                                self.dtype)
+                # the persistent counters accumulate across dispatches;
+                # reset them so every epoch starts from the program's
+                # expected zeros
+                for cname in e.win.counter_names():
+                    cur = e.state[cname]
+                    e.state[cname] = jax.device_put(
+                        jnp.zeros(cur.shape, cur.dtype), cur.sharding)
+            sync_kw = dict(mode=self.mode, donate=False)
+            if e.config is not None:
+                sync_kw["config"] = e.config
+            e.state = e.stream.synchronize(e.state, **sync_kw)
+            e.dispatches += 1
+            with spans.span("router.readback"):
+                q = e.win.qual
+                names = ("outtok", "mirror") + (("hmir",) if self.moe_on
+                                                else ())
+                got = [np.asarray(e.state[q(n)]) for n in names]
+            tok, mirror = got[0][0, :A], got[1][0, :A]
+            hmir = got[2][0, :A] if self.moe_on else None
+            return tok, mirror, hmir
 
     # -- reporting ------------------------------------------------------------
     def stats(self) -> dict:
