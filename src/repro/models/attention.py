@@ -81,6 +81,37 @@ def _attend_grouped(q, k, v, q_pos, kv_valid_len, causal, scale):
     return out.reshape(B, Sq, H, v.shape[-1])
 
 
+def _attend_new_row(q, k, v, k_new, v_new, pos, scale):
+    """Decode attention of q (B,1,H,hd) over the cache positions < pos of
+    the layer's unmodified (B,S,KV,hd) slice plus the new (B,1,KV,hd) row
+    at pos: :func:`_attend_grouped` over the slice with that row written,
+    without building the written slice. The softmax's max and sum run
+    over both parts in float32; a pos off the end of the slice (>= S)
+    leaves the row out, as the where-mask write leaves the slice as it
+    was."""
+    B, _, H, hd = q.shape
+    S, KV = k.shape[1], k.shape[2]
+    qg = q.reshape(B, 1, KV, H // KV, hd)
+    s_old = jnp.einsum("bqkgh,bskh->bkgqs", qg,
+                       k).astype(jnp.float32) * scale
+    s_new = jnp.einsum("bqkgh,bskh->bkgqs", qg,
+                       k_new).astype(jnp.float32) * scale
+    old_ok = jnp.arange(S)[None, :] < pos[:, None]
+    s_old = jnp.where(old_ok[:, None, None, None, :], s_old, NEG_INF)
+    s_new = jnp.where((pos < S)[:, None, None, None, None], s_new, NEG_INF)
+    m = jnp.maximum(jnp.max(s_old, axis=-1, keepdims=True), s_new)
+    e_old = jnp.exp(s_old - m)
+    e_new = jnp.exp(s_new - m)
+    den = jnp.sum(e_old, axis=-1, keepdims=True) + e_new
+    w_old = (e_old / den).astype(v.dtype)
+    w_new = (e_new / den).astype(v.dtype)
+    out = (jnp.einsum("bkgqs,bskh->bqkgh", w_old, v,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgqs,bskh->bqkgh", w_new, v_new,
+                        preferred_element_type=jnp.float32))
+    return out.astype(v.dtype).reshape(B, 1, H, v.shape[-1])
+
+
 def _expand_kv(k, H):
     """(B,S,KV,hd) -> (B,S,H,hd), repeating each kv head H/KV times.
     Keeps the head axis aligned with q heads so a 'heads->model' shard
@@ -171,11 +202,17 @@ def _update_cache(cache_k, k_new, pos):
 
     Three partition-friendly paths:
       * full overwrite (prefill writes the whole range): no read at all;
-      * S==1 (decode): elementwise where-mask — works with ANY sharding of
-        the sequence dim (a dynamic_update_slice at a traced index forces
-        SPMD to all-gather a sharded cache: +19 GB/device at llama-90b
-        decode_32k);
+      * S==1 (decode) on a cache whose sequence dim is sharded: elementwise
+        where-mask — works with ANY sharding of the sequence dim (a
+        dynamic_update_slice at a traced index forces SPMD to all-gather a
+        sharded cache: +19 GB/device at llama-90b decode_32k), at the cost
+        of rewriting the whole slice every step;
       * partial prefill (serving engine): per-row dynamic update.
+
+    The fourth write, for S==1 on a cache whose sequence dim is on no mesh
+    axis (:func:`decode_cache_write` says "rows"), is not here: the layer
+    returns only its new rows and ``model.forward`` writes them into the
+    donated cache after the layer scan (:func:`write_rows`).
     """
     B, S_new = k_new.shape[:2]
     S = cache_k.shape[1]
@@ -191,17 +228,66 @@ def _update_cache(cache_k, k_new, pos):
     return jax.vmap(upd)(cache_k, k_new, pos)
 
 
+def decode_cache_write(rules) -> str:
+    """How a one-token decode step writes a self-attention layer's new K/V
+    rows into a cache laid out by ``rules``: "rows" (the layer returns the
+    rows and :func:`write_rows` writes them in place) when the cache's
+    ``kv_seq`` axis is on no mesh axis of size > 1; "mask" (the where-mask
+    of :func:`_update_cache`) when it is sharded."""
+    if rules.mesh is None:
+        return "rows"
+    m = rules.map.get("kv_seq")
+    axes = () if m is None else ((m,) if isinstance(m, str) else tuple(m))
+    size = 1
+    for a in axes:
+        size *= rules.mesh.shape.get(a, 1) if a is not None else 1
+    return "rows" if size == 1 else "mask"
+
+
+def write_rows(cache, rows, pos):
+    """Write each slot's new row into the cache at its position: cache
+    leaves (..., B, S, KV, hd), row leaves (..., B, KV, hd), pos (B,).
+    Every slot is written; a position outside [0, S) writes nothing, as
+    the where-mask write does.
+
+    Per slot, the row goes into the 128-position window that holds it
+    (read, where-mask, dynamic_update_slice back): on a donated cache XLA
+    updates that window in place, in the layout the cache has. The TPU
+    keeps this cache with its sequence axis minor-most, and a one-row
+    update or a scatter makes its compiler copy the whole cache into
+    another layout and back."""
+    def put(c, r):
+        r = r.astype(c.dtype)
+        lead, S = c.ndim - 4, c.shape[-3]
+        W = min(128, S)
+        for b in range(pos.shape[0]):
+            p = pos[b]
+            s0 = jnp.clip((p // W) * W, 0, S - W)
+            start = (0,) * lead + (b, s0, 0, 0)
+            win = jax.lax.dynamic_slice(
+                c, start, c.shape[:lead] + (1, W) + c.shape[-2:])
+            hit = (jnp.arange(W) == p - s0)[:, None, None]
+            win = jnp.where(hit, r[..., b:b + 1, None, :, :], win)
+            c = jax.lax.dynamic_update_slice(c, win, start)
+        return c
+
+    return {name: put(cache[name], rows[name]) for name in cache}
+
+
 # ---------------------------------------------------------------------------
 # Block apply
 # ---------------------------------------------------------------------------
 
 def attention(cfg, params, x, *, rules, positions, cache=None,
-              vision=None, cross: bool = False):
+              vision=None, cross: bool = False, rows_only: bool = False):
     """Pre-norm'd x -> attention output (+ updated cache).
 
     x: (B, S, D); positions: (B, S) absolute positions.
     cache: dict from attn_cache_specs (decode/prefill) or None (train).
     vision: (B, T_vis, D) projected patch embeddings (cross layers only).
+    rows_only: a one-token decode whose cache ``kv_seq`` is unsharded
+    (:func:`decode_cache_write`): the cache is only read, and the second
+    result is the new rows {"k", "v"} (B, KV, hd) for :func:`write_rows`.
     """
     dt = x.dtype
     B, S, D = x.shape
@@ -241,6 +327,24 @@ def attention(cfg, params, x, *, rules, positions, cache=None,
     q = rules.constrain(q, ("batch", None, "heads", None))
     k = rules.constrain(k, ("batch", None, "kv_heads", None))
     v = rules.constrain(v, ("batch", None, "kv_heads", None))
+
+    if rows_only:
+        pos0 = positions[:, 0]
+        rows = {"k": k[:, 0].astype(cache["k"].dtype),
+                "v": v[:, 0].astype(cache["v"].dtype)}
+        k_new = rows["k"][:, None].astype(dt)
+        v_new = rows["v"][:, None].astype(dt)
+        if cfg.attn_impl == "xla":
+            out = _attend_new_row(q, cache["k"].astype(dt),
+                                  cache["v"].astype(dt), k_new, v_new,
+                                  pos0, 1.0 / (q.shape[-1] ** 0.5))
+        else:   # the Pallas decode kernel reads one written slice
+            out = attention_core(
+                cfg, q, _update_cache(cache["k"].astype(dt), k_new, pos0),
+                _update_cache(cache["v"].astype(dt), v_new, pos0),
+                q_positions=positions, kv_valid_len=pos0 + 1, causal=True)
+        out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
+        return out, rows
 
     new_cache = None
     kv_valid_len = None

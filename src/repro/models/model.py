@@ -114,7 +114,7 @@ def cache_specs(cfg, batch: int, max_len: int, cache_dtype=jnp.bfloat16):
 # ---------------------------------------------------------------------------
 
 def _apply_block(cfg, spec, params, x, *, rules, positions, cache,
-                 vision, moe_impl):
+                 vision, moe_impl, rows_only=False):
     mixer, ffn_kind = spec
     dt = x.dtype
     aux = jnp.zeros((), jnp.float32)
@@ -123,7 +123,8 @@ def _apply_block(cfg, spec, params, x, *, rules, positions, cache,
     new_cache = cache
     if mixer == "attn":
         out, nc = attn_mod.attention(cfg, params["mixer"], h, rules=rules,
-                                     positions=positions, cache=cache)
+                                     positions=positions, cache=cache,
+                                     rows_only=rows_only)
     elif mixer == "cross":
         out, nc = attn_mod.attention(cfg, params["mixer"], h, rules=rules,
                                      positions=positions, cache=cache,
@@ -194,6 +195,15 @@ def cast_big_params(cfg, params, rules):
                         is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
+def decode_cache_write(cfg, rules):
+    """The write path of a one-token decode step's self-attention K/V rows
+    (``attention.decode_cache_write``): "rows" or "mask"; None for a model
+    without self-attention blocks."""
+    if not any(m == "attn" for m, _ in cfg.layer_specs()):
+        return None
+    return attn_mod.decode_cache_write(rules)
+
+
 def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
             unroll=False):
     """Forward pass.
@@ -205,10 +215,30 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
       "positions" (B,S) int32 absolute positions
     cache: cache tree (decode/prefill) or None (train)
     Returns (hidden (B,S,D), new_cache, aux_loss).
+
+    The self-attention K/V cache is written one of four ways
+    (``attention._update_cache``): prefill overwrites it whole or per
+    row; a one-token decode on a cache whose ``kv_seq`` is sharded
+    where-masks the whole slice, layer by layer, and the scan returns the
+    rewritten cache. A one-token decode on an unsharded ``kv_seq``
+    (:func:`decode_cache_write` is "rows") keeps the cache read-only in
+    the layers: each returns its new (B, KV, hd) rows, the scan stacks
+    them, and ``attention.write_rows`` after the scan writes them into
+    the cache passed in, in place when the caller donates it. Other
+    cache leaves (cross-attention, MLA, mamba, rwkv) come back whole.
     """
     groups = cfg.layer_groups()
     cdt = jnp.dtype(cfg.compute_dtype)
     positions = batch["positions"]
+    rows_only = (cache is not None and positions.shape[1] == 1
+                 and decode_cache_write(cfg, rules) == "rows")
+
+    def merge(spec, old, new):
+        """A block's cache output as the new cache: write rows_only
+        attention rows into the old cache, take any other as it is."""
+        if rows_only and spec[0] == "attn":
+            return attn_mod.write_rows(old, new, positions[:, 0])
+        return new
 
     if "frames" in batch and cfg.family == "audio":
         x = L.frontend(params["frontend"], batch["frames"], cdt) \
@@ -232,9 +262,10 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
         def pre_block(x_, params_, cache_):
             return _apply_block(cfg, sp, params_, x_, rules=rules,
                                 positions=positions, cache=cache_,
-                                vision=vision, moe_impl=moe_impl)
+                                vision=vision, moe_impl=moe_impl,
+                                rows_only=rows_only)
         x, nc, aux = _maybe_remat(cfg, pre_block)(x, params["prefix"][i], c)
-        new_prefix_caches.append(nc)
+        new_prefix_caches.append(merge(sp, c, nc))
         aux_total += aux
 
     new_unit_caches = [None] * len(groups.unit)
@@ -251,7 +282,7 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
                 x_, nc, aux_i = _apply_block(
                     cfg, sp, p_slices[pos_i], x_, rules=rules,
                     positions=positions, cache=c_slices[pos_i],
-                    vision=vision, moe_impl=moe_impl)
+                    vision=vision, moe_impl=moe_impl, rows_only=rows_only)
                 ncs.append(nc)
                 aux_ = aux_ + aux_i
             return (x_, aux_), tuple(ncs)
@@ -260,7 +291,8 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
         (x, aux_total), new_stacked = jax.lax.scan(
             body, (x, aux_total), (unit_params, unit_caches),
             unroll=True if unroll else 1)
-        new_unit_caches = list(new_stacked)
+        new_unit_caches = [merge(sp, old, new) for sp, old, new in
+                           zip(groups.unit, unit_caches, new_stacked)]
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     new_cache = None

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import cache_specs
+from repro.models.model import decode_cache_write
 from repro.models.params import is_spec
 from repro.runtime import spans
 from repro.train.steps import make_decode_sample_step, make_prefill_sample_step
@@ -87,6 +88,10 @@ class ServingEngine:
         self._decode_sample = jax.jit(
             make_decode_sample_step(cfg, rules, moe_impl=moe_impl),
             donate_argnums=(2,))
+        # how the decode step writes its K/V rows ("rows": in place into
+        # the donated cache; "mask": the whole slice rewritten), fixed by
+        # the rules the step is traced with
+        self.decode_cache_write = decode_cache_write(cfg, rules)
         cspecs = cache_specs(cfg, batch_slots, max_len)
         self.cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), cspecs, is_leaf=is_spec)
@@ -295,7 +300,8 @@ class ServingEngine:
              "prefill_dispatches": self.prefill_dispatches,
              "decode_steps": self.decode_steps,
              "tokens_generated": self.tokens_generated,
-             "st_mode": self.st_mode}
+             "st_mode": self.st_mode,
+             "decode_cache_write": self.decode_cache_write}
         if self._router is not None:
             d["st"] = self._router.stats()
         return d
