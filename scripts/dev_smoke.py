@@ -25,7 +25,7 @@ def run(arch):
     if cfg.family == "vlm":
         batch["vision"] = jnp.ones((B, cfg.vision.num_tokens,
                                     cfg.vision.raw_dim), jnp.float32) * 0.1
-    x, _, aux = forward(cfg, params, batch, rules=rules, moe_impl="dense")
+    x, _, aux, _ = forward(cfg, params, batch, rules=rules, moe_impl="dense")
     logits = logits_from_hidden(cfg, params, x, rules)
     targets = jnp.zeros((B, S), jnp.int32)
     loss = lm_loss(cfg, logits, targets, rules)
@@ -38,8 +38,8 @@ def run(arch):
               "positions": jnp.zeros((B, 1), jnp.int32)}
     if cfg.family == "vlm":
         dbatch["vision"] = batch["vision"]
-    xd, ncache, _ = forward(cfg, params, dbatch, rules=rules, cache=cache,
-                            moe_impl="dense")
+    xd, ncache, _, _ = forward(cfg, params, dbatch, rules=rules, cache=cache,
+                               moe_impl="dense")
     ld = logits_from_hidden(cfg, params, xd, rules, last_only=True)
     assert ld.shape == (B, 1, cfg.padded_vocab)
     assert np.isfinite(np.asarray(ld)).all()

@@ -18,23 +18,43 @@ from typing import Optional, Sequence
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int          # routed experts
+    num_experts: int          # routed experts: the router's outputs
     top_k: int
     expert_ff: int            # d_ff of each routed expert
     num_shared: int = 0       # shared (always-on) experts
     shared_ff: int = 0        # total d_ff of the shared expert block
     router_aux_coef: float = 0.01
     capacity_factor: float = 1.25
+    # routing rule (models/moe.route): "softmax" scores, or "sigmoid"
+    # (DeepSeek-V3's noaux_tc); a per-expert ``score_bias`` leaf that is
+    # added to the scores for the top-k choice only; the chosen scores
+    # renormalised to sum 1 (``norm_topk``), then times ``routed_scale``
+    scoring: str = "softmax"
+    score_bias: bool = False
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # (first, count): the routed experts this chip holds under expert
+    # parallelism; it routes over all ``num_experts`` and computes the
+    # held experts' part of the result. None: all of them
+    held: Optional[tuple] = None
+
+    @property
+    def held_range(self) -> tuple:
+        return self.held if self.held is not None else (0, self.num_experts)
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 multi-head latent attention."""
+    """DeepSeek-V2 multi-head latent attention. ``q_lora_rank`` 0: the
+    query is projected from the input directly (no query latent), as in
+    Moonlight. ``rope_interleave``: the rope dims rotate in adjacent
+    pairs (DeepSeek-V3's layout), not as two halves."""
     kv_lora_rank: int = 512
     q_lora_rank: int = 1536
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    rope_interleave: bool = False
 
 
 @dataclass(frozen=True)
@@ -222,9 +242,10 @@ class ModelConfig:
             elif mixer == "mla":
                 m = self.mla
                 qh = self.num_heads
-                p = (d * m.q_lora_rank
-                     + m.q_lora_rank * qh * (m.qk_nope_head_dim + m.qk_rope_head_dim)
-                     + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+                q = (d * m.q_lora_rank + m.q_lora_rank * qh * qk
+                     if m.q_lora_rank else d * qh * qk)
+                p = (q + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                      + m.kv_lora_rank * qh * (m.qk_nope_head_dim + m.v_head_dim)
                      + qh * m.v_head_dim * d)
                 total += p; active += p
@@ -246,7 +267,7 @@ class ModelConfig:
             elif ffn == "moe":
                 mo = self.moe
                 pe = 3 * d * mo.expert_ff
-                total += mo.num_experts * pe + d * mo.num_experts
+                total += mo.held_range[1] * pe + d * mo.num_experts
                 active += mo.top_k * pe + d * mo.num_experts
                 if mo.num_shared:
                     ps = 3 * d * mo.shared_ff
@@ -274,14 +295,21 @@ class ModelConfig:
             optimizer="adamw",
         )
         if self.moe is not None:
+            mo = self.moe
+            e = min(mo.num_experts, 8)
+            held = None
+            if mo.held is not None:     # the same share of fewer experts
+                first, count = mo.held
+                held = (first * e // mo.num_experts,
+                        max(1, count * e // mo.num_experts))
             changes["moe"] = dataclasses.replace(
-                self.moe, num_experts=min(self.moe.num_experts, 8),
-                top_k=min(self.moe.top_k, 2), expert_ff=64,
-                shared_ff=64 if self.moe.num_shared else 0)
+                mo, num_experts=e, top_k=min(mo.top_k, 2), expert_ff=64,
+                shared_ff=64 if mo.num_shared else 0, held=held)
         if self.mla is not None:
-            changes["mla"] = MLAConfig(kv_lora_rank=32, q_lora_rank=48,
-                                       qk_nope_head_dim=32, qk_rope_head_dim=16,
-                                       v_head_dim=32)
+            changes["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32,
+                q_lora_rank=48 if self.mla.q_lora_rank else 0,
+                qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32)
         if self.mamba is not None:
             changes["mamba"] = MambaConfig(d_state=8, d_conv=4, expand=2, dt_rank=8)
         if self.rwkv is not None:
@@ -339,6 +367,7 @@ ARCH_IDS = [
     "deepseek-v2-236b",
     "deepseek-moe-16b",
     "rwkv6-1.6b",
+    "moonlight-16b-a3b",
 ]
 
 _MODULES = {
@@ -352,6 +381,7 @@ _MODULES = {
     "deepseek-v2-236b": "deepseek_v2_236b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "rwkv6-1.6b": "rwkv6_1_6b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

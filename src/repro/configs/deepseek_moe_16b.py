@@ -2,6 +2,8 @@
 vocab=102400, 2 shared + 64 routed top-6, fine-grained. [arXiv:2401.06066; hf]
 
 First layer dense FFN (width 10944) per the HF config; layers 1..27 MoE.
+The chosen experts' softmax scores are not renormalised
+(norm_topk_prob false).
 """
 from repro.configs.base import ModelConfig, MoEConfig
 
@@ -17,7 +19,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     rope_theta=10_000.0,
     moe=MoEConfig(num_experts=64, top_k=6, expert_ff=1408,
-                  num_shared=2, shared_ff=2816),
+                  num_shared=2, shared_ff=2816, norm_topk=False),
     first_dense_ff=10944,
     grad_accum=2,
     remat="dots",

@@ -5,6 +5,8 @@ vocab=102400, MLA kv_lora=512, 2 shared + 160 routed top-6.
 First layer uses a dense FFN (width 12288) per the HF config; layers 1..59
 are MoE. MLA: q_lora 1536, kv_lora 512, nope 128 / rope 64 per head,
 v_head_dim 128.
+The chosen experts' softmax scores are not renormalised
+(norm_topk_prob false).
 """
 from repro.configs.base import ModelConfig, MoEConfig, MLAConfig
 
@@ -20,7 +22,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     rope_theta=10_000.0,
     moe=MoEConfig(num_experts=160, top_k=6, expert_ff=1536,
-                  num_shared=2, shared_ff=3072),
+                  num_shared=2, shared_ff=3072, norm_topk=False),
     first_dense_ff=12288,
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=1536,
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),

@@ -29,7 +29,8 @@ import jax.numpy as jnp
 
 from repro.core.compat import shard_map
 from repro.core.patterns import register_pattern, shifts_topology
-from repro.models.moe import _capacity, _shared
+from repro.configs.base import MoEConfig
+from repro.models.moe import _capacity, _shared, route
 
 
 def moe_a2a(cfg, params, x, rules):
@@ -85,14 +86,7 @@ def _moe_shard(cfg, xl, router, wg, wu, wd, shard_id, e_l):
     T = Bl * S
     xt = xl.reshape(T, D)
 
-    logits = (xt @ router).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, sel = jax.lax.top_k(probs, mo.top_k)                 # (T,k)
-    gates = gates / jnp.clip(gates.sum(-1, keepdims=True), 1e-9)
-    me = probs.mean(axis=0)
-    ce = jnp.mean(jax.nn.one_hot(sel, mo.num_experts,
-                                 dtype=jnp.float32), axis=(0, 1))
-    aux = mo.router_aux_coef * mo.num_experts * jnp.sum(me * ce) * mo.top_k
+    gates, sel, aux = route(mo, xt, router)                     # (T,k)
 
     C = _capacity(cfg, max(T, 4))
     e0 = shard_id * e_l
@@ -148,9 +142,8 @@ def _moe_local(cfg, params, x, rules, n_shards, shard_id):
 def _tiny_moe_cfg(experts, top_k, expert_ff):
     """cfg duck-type for the self-contained (benchmark / device-free)
     path; ``moe_a2a_st`` passes a real ModelConfig instead."""
-    return SimpleNamespace(moe=SimpleNamespace(
-        num_experts=experts, top_k=top_k, expert_ff=expert_ff,
-        router_aux_coef=0.01, capacity_factor=1.25, num_shared=0))
+    return SimpleNamespace(moe=MoEConfig(
+        num_experts=experts, top_k=top_k, expert_ff=expert_ff))
 
 
 def make_moe_a2a_kernels(cfg, axis, n_shards):

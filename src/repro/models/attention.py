@@ -244,11 +244,12 @@ def decode_cache_write(rules) -> str:
     return "rows" if size == 1 else "mask"
 
 
-def write_rows(cache, rows, pos):
+def write_rows(cache, rows, pos, lead=None):
     """Write each slot's new row into the cache at its position: cache
-    leaves (..., B, S, KV, hd), row leaves (..., B, KV, hd), pos (B,).
-    Every slot is written; a position outside [0, S) writes nothing, as
-    the where-mask write does.
+    leaves (*L, B, S, *row), row leaves (*L, B, *row), pos (B,), with
+    ``lead`` = len(L) leading (layer) axes; by default those of a K/V
+    leaf, whose row is (KV, hd). Every slot is written; a position
+    outside [0, S) writes nothing, as the where-mask write does.
 
     Per slot, the row goes into the 128-position window that holds it
     (read, where-mask, dynamic_update_slice back): on a donated cache XLA
@@ -258,16 +259,19 @@ def write_rows(cache, rows, pos):
     another layout and back."""
     def put(c, r):
         r = r.astype(c.dtype)
-        lead, S = c.ndim - 4, c.shape[-3]
+        n = c.ndim - 4 if lead is None else lead
+        S = c.shape[n + 1]
+        tail = c.ndim - n - 2
         W = min(128, S)
         for b in range(pos.shape[0]):
             p = pos[b]
             s0 = jnp.clip((p // W) * W, 0, S - W)
-            start = (0,) * lead + (b, s0, 0, 0)
+            start = (0,) * n + (b, s0) + (0,) * tail
             win = jax.lax.dynamic_slice(
-                c, start, c.shape[:lead] + (1, W) + c.shape[-2:])
-            hit = (jnp.arange(W) == p - s0)[:, None, None]
-            win = jnp.where(hit, r[..., b:b + 1, None, :, :], win)
+                c, start, c.shape[:n] + (1, W) + c.shape[n + 2:])
+            hit = (jnp.arange(W) == p - s0)[(slice(None),) + (None,) * tail]
+            win = jnp.where(hit, r[(Ellipsis, slice(b, b + 1), None)
+                                   + (slice(None),) * tail], win)
             c = jax.lax.dynamic_update_slice(c, win, start)
         return c
 

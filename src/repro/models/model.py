@@ -118,6 +118,7 @@ def _apply_block(cfg, spec, params, x, *, rules, positions, cache,
     mixer, ffn_kind = spec
     dt = x.dtype
     aux = jnp.zeros((), jnp.float32)
+    sel = None
 
     h = L.rmsnorm(params["norm1"], x, cfg.norm_eps)
     new_cache = cache
@@ -131,7 +132,8 @@ def _apply_block(cfg, spec, params, x, *, rules, positions, cache,
                                      vision=vision, cross=True)
     elif mixer == "mla":
         out, nc = mla_mod.mla_attention(cfg, params["mixer"], h, rules=rules,
-                                        positions=positions, cache=cache)
+                                        positions=positions, cache=cache,
+                                        rows_only=rows_only)
     elif mixer == "mamba":
         out, nc = mamba_mod.mamba(cfg, params["mixer"], h, rules=rules,
                                   cache=cache,
@@ -150,7 +152,8 @@ def _apply_block(cfg, spec, params, x, *, rules, positions, cache,
     if ffn_kind == "dense":
         out2 = L.ffn(params["ffn"], h2, rules)
     elif ffn_kind == "moe":
-        out2, aux = moe_mod.moe(cfg, params["ffn"], h2, rules, impl=moe_impl)
+        out2, aux, sel = moe_mod.moe(cfg, params["ffn"], h2, rules,
+                                     impl=moe_impl)
     elif ffn_kind == "rwkv":
         out2, nc2 = rwkv_mod.channel_mix(cfg, params["ffn"], h2,
                                          rules=rules, cache=new_cache)
@@ -158,7 +161,7 @@ def _apply_block(cfg, spec, params, x, *, rules, positions, cache,
             new_cache = nc2
     out2 = _ckpt_name(out2, "block_out")
     x = rules.constrain(x + out2, ("batch", "seq_act", None))
-    return x, new_cache, aux
+    return x, new_cache, aux, sel
 
 
 def _maybe_remat(cfg, fn):
@@ -195,11 +198,14 @@ def cast_big_params(cfg, params, rules):
                         is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
+ROW_MIXERS = ("attn", "mla")     # blocks whose decode can return its rows
+
+
 def decode_cache_write(cfg, rules):
-    """The write path of a one-token decode step's self-attention K/V rows
-    (``attention.decode_cache_write``): "rows" or "mask"; None for a model
-    without self-attention blocks."""
-    if not any(m == "attn" for m, _ in cfg.layer_specs()):
+    """The write path of a one-token decode step's self-attention K/V or
+    latent rows (``attention.decode_cache_write``): "rows" or "mask";
+    None for a model without self-attention or MLA blocks."""
+    if not any(m in ROW_MIXERS for m, _ in cfg.layer_specs()):
         return None
     return attn_mod.decode_cache_write(rules)
 
@@ -214,18 +220,22 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
       "vision"  (B,Tv,raw_dim)           (vlm patch embeddings)
       "positions" (B,S) int32 absolute positions
     cache: cache tree (decode/prefill) or None (train)
-    Returns (hidden (B,S,D), new_cache, aux_loss).
+    Returns (hidden (B,S,D), new_cache, aux_loss, routes): ``routes`` is
+    the MoE layers' routing in layer order, {"sel": (MoE layers, B, S,
+    top_k) int32 experts each token chose}, and {} for a model without
+    MoE layers.
 
-    The self-attention K/V cache is written one of four ways
-    (``attention._update_cache``): prefill overwrites it whole or per
-    row; a one-token decode on a cache whose ``kv_seq`` is sharded
-    where-masks the whole slice, layer by layer, and the scan returns the
-    rewritten cache. A one-token decode on an unsharded ``kv_seq``
-    (:func:`decode_cache_write` is "rows") keeps the cache read-only in
-    the layers: each returns its new (B, KV, hd) rows, the scan stacks
-    them, and ``attention.write_rows`` after the scan writes them into
-    the cache passed in, in place when the caller donates it. Other
-    cache leaves (cross-attention, MLA, mamba, rwkv) come back whole.
+    The self-attention K/V cache and the MLA latent cache are written one
+    of four ways (``attention._update_cache``, ``mla._update_cache_2d``):
+    prefill overwrites them whole or per row; a one-token decode on a
+    cache whose ``kv_seq`` is sharded where-masks the whole slice, layer
+    by layer, and the scan returns the rewritten cache. A one-token
+    decode on an unsharded ``kv_seq`` (:func:`decode_cache_write` is
+    "rows") keeps the cache read-only in the layers: each returns its new
+    rows ((B, KV, hd) K/V, (B, kv_lora) / (B, rope) latent), the scan
+    stacks them, and ``attention.write_rows`` after the scan writes them
+    into the cache passed in, in place when the caller donates it. Other
+    cache leaves (cross-attention, mamba, rwkv) come back whole.
     """
     groups = cfg.layer_groups()
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -233,11 +243,15 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
     rows_only = (cache is not None and positions.shape[1] == 1
                  and decode_cache_write(cfg, rules) == "rows")
 
-    def merge(spec, old, new):
+    def merge(spec, old, new, lead):
         """A block's cache output as the new cache: write rows_only
-        attention rows into the old cache, take any other as it is."""
+        attention or latent rows into the old cache, take any other as
+        it is."""
         if rows_only and spec[0] == "attn":
             return attn_mod.write_rows(old, new, positions[:, 0])
+        if rows_only and spec[0] == "mla":
+            with jax.named_scope("mla.write"):
+                return attn_mod.write_rows(old, new, positions[:, 0], lead)
         return new
 
     if "frames" in batch and cfg.family == "audio":
@@ -255,6 +269,7 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
         vision = rules.constrain(vision, ("batch", None, None))
 
     aux_total = jnp.zeros((), jnp.float32)
+    sels = []                   # per MoE block, leading layer axis
     new_prefix_caches = []
     for i, sp in enumerate(groups.prefix):
         c = cache["prefix"][i] if cache is not None else None
@@ -264,9 +279,12 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
                                 positions=positions, cache=cache_,
                                 vision=vision, moe_impl=moe_impl,
                                 rows_only=rows_only)
-        x, nc, aux = _maybe_remat(cfg, pre_block)(x, params["prefix"][i], c)
-        new_prefix_caches.append(merge(sp, c, nc))
+        x, nc, aux, sel = _maybe_remat(cfg, pre_block)(
+            x, params["prefix"][i], c)
+        new_prefix_caches.append(merge(sp, c, nc, 0))
         aux_total += aux
+        if sel is not None:
+            sels.append(sel[None])
 
     new_unit_caches = [None] * len(groups.unit)
     if groups.repeats:
@@ -277,28 +295,34 @@ def forward(cfg, params, batch, *, rules, cache=None, moe_impl="gshard",
         def unit_body(carry, xs):
             x_, aux_ = carry
             p_slices, c_slices = xs
-            ncs = []
+            ncs, unit_sel = [], []
             for pos_i, sp in enumerate(groups.unit):
-                x_, nc, aux_i = _apply_block(
+                x_, nc, aux_i, sel = _apply_block(
                     cfg, sp, p_slices[pos_i], x_, rules=rules,
                     positions=positions, cache=c_slices[pos_i],
                     vision=vision, moe_impl=moe_impl, rows_only=rows_only)
                 ncs.append(nc)
                 aux_ = aux_ + aux_i
-            return (x_, aux_), tuple(ncs)
+                if sel is not None:
+                    unit_sel.append(sel)
+            return (x_, aux_), (tuple(ncs), tuple(unit_sel))
 
         body = _maybe_remat(cfg, unit_body) if cfg.remat != "none" else unit_body
-        (x, aux_total), new_stacked = jax.lax.scan(
+        (x, aux_total), (new_stacked, unit_sel) = jax.lax.scan(
             body, (x, aux_total), (unit_params, unit_caches),
             unroll=True if unroll else 1)
-        new_unit_caches = [merge(sp, old, new) for sp, old, new in
+        new_unit_caches = [merge(sp, old, new, 1) for sp, old, new in
                            zip(groups.unit, unit_caches, new_stacked)]
+        if unit_sel:            # (repeats, blocks, ...) in layer order
+            sels.append(jnp.stack(unit_sel, 1).reshape(
+                -1, *unit_sel[0].shape[1:]))
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     new_cache = None
     if cache is not None:
         new_cache = {"prefix": new_prefix_caches, "unit": new_unit_caches}
-    return x, new_cache, aux_total
+    routes = {"sel": jnp.concatenate(sels)} if sels else {}
+    return x, new_cache, aux_total, routes
 
 
 def logits_from_hidden(cfg, params, x, rules, last_only: bool = False):

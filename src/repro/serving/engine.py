@@ -35,6 +35,16 @@ from the two stamps, so no profiler capture holds it); and
 host), ``engine.extract`` (the ST payload's extract and readback) and
 ``engine.commit`` (token bookkeeping); the router's ``router.dispatch``
 runs between the last two.
+
+``moe_impl`` names the MoE layers' implementation (``models/moe.py``):
+"dense" by default, "ragged" for the dropless grouped matmul. A MoE
+model's steps count the assignments each held expert received from the
+slots that serve a request (the batch's ``live`` rows), layer by layer;
+:meth:`stats` gives them per held expert, summed over layers and every
+prefill and decode call (``moe_held_assignments``). The steps also
+return the experts each token chose: after :meth:`keep_routes` the
+engine keeps them, on the device, for every request it serves, and
+:meth:`routes` gives a request's.
 """
 from __future__ import annotations
 
@@ -103,6 +113,14 @@ class ServingEngine:
         self.decode_steps = 0
         self.steps = 0
         self.tokens_generated = 0
+        self.moe_impl = moe_impl
+        # per held expert, summed over layers and calls (MoE models)
+        self.moe_held_assignments = None
+        if cfg.moe is not None:
+            self.moe_held_assignments = np.zeros(cfg.moe.held_range[1],
+                                                 np.int64)
+        # req_id -> [(chosen experts of a step, slot)], after keep_routes
+        self._routes: Optional[Dict[int, list]] = None
         self.st_mode = st_mode
         self._router = None
         self._extract = None
@@ -189,7 +207,10 @@ class ServingEngine:
                     batch["vision"] = jnp.zeros(
                         (self.B, self.cfg.vision.num_tokens,
                          self.cfg.vision.raw_dim), jnp.float32)
-                ids, new_cache = self._prefill_sample(self.params, batch)
+                if self.cfg.moe is not None:
+                    batch["live"] = self._live(slots)
+                ids, new_cache, *routing = self._prefill_sample(
+                    self.params, batch)
                 self.prefill_dispatches += 1
             # merge ONLY the group's cache rows (other slots keep
             # theirs). prefix-layer leaves are (B, ...); scanned-unit
@@ -207,6 +228,7 @@ class ServingEngine:
                 }
             # the wait for the prefill, in engine.admit's own time
             ids_np = np.asarray(ids)
+            self._take_routing(routing, zip(slots, reqs))
             now = time.monotonic()
             for slot, req in zip(slots, reqs):
                 req.out_tokens.append(int(ids_np[slot]))
@@ -249,13 +271,17 @@ class ServingEngine:
             batch["vision"] = jnp.zeros(
                 (self.B, self.cfg.vision.num_tokens,
                  self.cfg.vision.raw_dim), jnp.float32)
+        if self.cfg.moe is not None:
+            batch["live"] = self._live(active)
         pos_written = self.slot_pos.copy()      # rows this decode writes
         with spans.span("engine.decode"):
-            ids, hid, self.cache = self._decode_sample(self.params, batch,
-                                                       self.cache)
+            ids, hid, self.cache, *routing = self._decode_sample(
+                self.params, batch, self.cache)
         self.decode_steps += 1
         with spans.span("engine.ids"):
             ids_np = np.asarray(ids)
+            self._take_routing(routing,
+                               [(i, self.slot_req[i]) for i in active])
         if self._router is not None:
             act = np.asarray(active, np.int32)
             with spans.span("engine.extract"):
@@ -285,6 +311,43 @@ class ServingEngine:
                     self.slot_req[i] = None
         return len(active)
 
+    def _live(self, slots):
+        live = np.zeros(self.B, bool)
+        live[slots] = True
+        return jnp.asarray(live)
+
+    def _take_routing(self, routing, served):
+        """A MoE step's (layers, held) load into the per-expert sums, and
+        after :meth:`keep_routes` its chosen experts kept for each
+        (slot, request) it ``served``."""
+        if not routing:
+            return
+        load, sel = routing
+        self.moe_held_assignments += np.asarray(load).sum(0)
+        if self._routes is not None:
+            for slot, req in served:
+                self._routes.setdefault(req.req_id, []).append((sel, slot))
+
+    def keep_routes(self):
+        """From now on keep the experts every served position chose (a
+        MoE model's; the arrays stay on the device until :meth:`routes`
+        reads them)."""
+        self._routes = {}
+
+    def routes(self, req: Request) -> np.ndarray:
+        """(positions, MoE layers, top_k) int32: the experts chosen at
+        each position the engine fed for ``req`` since
+        :meth:`keep_routes`, in order: the prompt's, then each decode
+        step's input token's."""
+        K = self.cfg.moe.top_k
+        parts = []
+        for sel, slot in (self._routes or {}).get(req.req_id, []):
+            a = np.asarray(sel)                 # (B * S, layers * K)
+            parts.append(a.reshape(self.B, -1, a.shape[1] // K, K)[slot])
+        if not parts:
+            return np.zeros((0, 0, K), np.int32)
+        return np.concatenate(parts).astype(np.int32)
+
     def run_until_drained(self, max_steps: int = 10_000):
         steps = 0
         while (self.queue or self._active()) and steps < max_steps:
@@ -302,6 +365,9 @@ class ServingEngine:
              "tokens_generated": self.tokens_generated,
              "st_mode": self.st_mode,
              "decode_cache_write": self.decode_cache_write}
+        if self.moe_held_assignments is not None:
+            d["moe_impl"] = self.moe_impl
+            d["moe_held_assignments"] = self.moe_held_assignments.tolist()
         if self._router is not None:
             d["st"] = self._router.stats()
         return d

@@ -19,8 +19,8 @@ from repro.optim import cosine_schedule, opt_update
 
 def _loss_fn(cfg, rules, moe_impl, unroll, params, mbatch):
     params = cast_big_params(cfg, params, rules)
-    x, _, aux = forward(cfg, params, mbatch, rules=rules, moe_impl=moe_impl,
-                        unroll=unroll)
+    x, _, aux, _ = forward(cfg, params, mbatch, rules=rules,
+                           moe_impl=moe_impl, unroll=unroll)
     loss = lm_loss_fused(cfg, params, x, mbatch["targets"], rules)
     return loss + aux, (loss, aux)
 
@@ -118,18 +118,44 @@ def init_cache_in_jit(cfg, batch: int, max_len: int, rules,
     return jax.tree.map(mk, cspecs, is_leaf=is_spec)
 
 
+def _prefill_body(cfg, rules, max_len, moe_impl, unroll):
+    """prefill(params, batch) -> (last_logits, cache, routes): the one
+    prefill body of the prefill steps (``routes`` as ``forward`` gives
+    them)."""
+
+    def prefill(params, batch):
+        B, S = batch["positions"].shape
+        cache = init_cache_in_jit(cfg, B, max_len or S, rules)
+        x, new_cache, _, routes = forward(cfg, params, batch, rules=rules,
+                                          cache=cache, moe_impl=moe_impl,
+                                          unroll=unroll)
+        logits = logits_from_hidden(cfg, params, x, rules, last_only=True)
+        return logits, new_cache, routes
+
+    return prefill
+
+
+def _decode_body(cfg, rules, moe_impl, unroll):
+    """decode(params, batch, cache) -> (logits (B,1,V), hidden (B,1,D),
+    new_cache, routes): the one decode body of the decode steps."""
+
+    def decode(params, batch, cache):
+        x, new_cache, _, routes = forward(cfg, params, batch, rules=rules,
+                                          cache=cache, moe_impl=moe_impl,
+                                          unroll=unroll)
+        logits = logits_from_hidden(cfg, params, x, rules, last_only=True)
+        return logits, x, new_cache, routes
+
+    return decode
+
+
 def make_prefill_step(cfg, rules, max_len: Optional[int] = None,
                       moe_impl: str = "gshard", unroll: bool = False):
     """prefill_step(params, batch) -> (last_logits, cache)."""
+    body = _prefill_body(cfg, rules, max_len, moe_impl, unroll)
 
     def prefill_step(params, batch):
-        B, S = batch["positions"].shape
-        cache = init_cache_in_jit(cfg, B, max_len or S, rules)
-        x, new_cache, _ = forward(cfg, params, batch, rules=rules,
-                                  cache=cache, moe_impl=moe_impl,
-                                  unroll=unroll)
-        logits = logits_from_hidden(cfg, params, x, rules, last_only=True)
-        return logits, new_cache
+        return body(params, batch)[:2]
 
     return prefill_step
 
@@ -137,12 +163,10 @@ def make_prefill_step(cfg, rules, max_len: Optional[int] = None,
 def make_decode_step(cfg, rules, moe_impl: str = "gshard",
                      unroll: bool = False):
     """decode_step(params, batch, cache) -> (logits (B,1,V), new_cache)."""
+    body = _decode_body(cfg, rules, moe_impl, unroll)
 
     def decode_step(params, batch, cache):
-        x, new_cache, _ = forward(cfg, params, batch, rules=rules,
-                                  cache=cache, moe_impl=moe_impl,
-                                  unroll=unroll)
-        logits = logits_from_hidden(cfg, params, x, rules, last_only=True)
+        logits, _, new_cache, _ = body(params, batch, cache)
         return logits, new_cache
 
     return decode_step
@@ -156,17 +180,42 @@ def _greedy_ids(cfg, logits):
                       axis=-1).astype(jnp.int32)
 
 
+def _routing(cfg, routes, batch) -> tuple:
+    """A MoE model's step results past the tokens and cache, () for
+    another: the load, (MoE layers, held experts) int32 assignments each
+    held expert received from the batch's live rows (``batch["live"]``,
+    (B,) bool, marks the slots that serve a request; every row counts
+    where it is absent), and the experts each token chose, (B * S, MoE
+    layers * top_k) in (batch, position) and (layer, choice) order, as
+    uint8 where the experts fit it: two wide axes, so that the device
+    pads a decode step's few bytes to few more."""
+    if not routes:
+        return ()
+    sel = routes["sel"]                                  # (L, B, S, K)
+    first, n = cfg.moe.held_range
+    hit = sel[..., None] == first + jnp.arange(n, dtype=sel.dtype)
+    if "live" in batch:
+        hit = hit & batch["live"][None, :, None, None, None]
+    load = jnp.sum(hit, axis=(1, 2, 3), dtype=jnp.int32)
+    L, B, S, K = sel.shape
+    sel = jnp.transpose(sel, (1, 2, 0, 3)).reshape(B * S, L * K)
+    return load, sel.astype(jnp.uint8 if cfg.moe.num_experts <= 256
+                            else jnp.int32)
+
+
 def make_prefill_sample_step(cfg, rules, max_len: Optional[int] = None,
                              moe_impl: str = "gshard",
                              unroll: bool = False):
     """prefill_sample_step(params, batch) -> (ids (B,), cache): prefill
-    plus device-side greedy sampling of each slot's first token."""
-    step = make_prefill_step(cfg, rules, max_len=max_len,
-                             moe_impl=moe_impl, unroll=unroll)
+    plus device-side greedy sampling of each slot's first token. A MoE
+    model's step also returns its load and chosen experts
+    (:func:`_routing`)."""
+    body = _prefill_body(cfg, rules, max_len, moe_impl, unroll)
 
     def prefill_sample_step(params, batch):
-        logits, cache = step(params, batch)
-        return _greedy_ids(cfg, logits), cache
+        logits, cache, routes = body(params, batch)
+        return (_greedy_ids(cfg, logits), cache,
+                *_routing(cfg, routes, batch))
 
     return prefill_sample_step
 
@@ -176,13 +225,13 @@ def make_decode_sample_step(cfg, rules, moe_impl: str = "gshard",
     """decode_sample_step(params, batch, cache) -> (ids (B,), hid (B, D),
     new_cache): one decode step plus device-side greedy sampling. The
     last-position hidden block rides along as the MoE-dispatch payload
-    of the ST serving path (ignored by the baseline)."""
+    of the ST serving path (ignored by the baseline). A MoE model's step
+    also returns its load and chosen experts, as the prefill step does."""
+    body = _decode_body(cfg, rules, moe_impl, unroll)
 
     def decode_sample_step(params, batch, cache):
-        x, new_cache, _ = forward(cfg, params, batch, rules=rules,
-                                  cache=cache, moe_impl=moe_impl,
-                                  unroll=unroll)
-        logits = logits_from_hidden(cfg, params, x, rules, last_only=True)
-        return _greedy_ids(cfg, logits), x[:, -1, :], new_cache
+        logits, x, new_cache, routes = body(params, batch, cache)
+        return (_greedy_ids(cfg, logits), x[:, -1, :], new_cache,
+                *_routing(cfg, routes, batch))
 
     return decode_sample_step

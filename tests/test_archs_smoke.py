@@ -38,7 +38,7 @@ def test_forward_shapes_no_nan(arch):
     params = init_p(model_specs(cfg), jax.random.PRNGKey(0))
     B, S = 2, 32
     batch = _batch_for(cfg, B, S)
-    x, _, aux = forward(cfg, params, batch, rules=rules, moe_impl="dense")
+    x, _, aux, _ = forward(cfg, params, batch, rules=rules, moe_impl="dense")
     assert x.shape == (B, S, cfg.d_model)
     logits = logits_from_hidden(cfg, params, x, rules)
     assert logits.shape == (B, S, cfg.padded_vocab)
@@ -80,8 +80,8 @@ def test_decode_matches_shapes(arch):
     if cfg.family == "vlm":
         batch["vision"] = jnp.full(
             (B, cfg.vision.num_tokens, cfg.vision.raw_dim), 0.1, jnp.float32)
-    x, ncache, _ = forward(cfg, params, batch, rules=rules, cache=cache,
-                           moe_impl="dense")
+    x, ncache, _, _ = forward(cfg, params, batch, rules=rules, cache=cache,
+                              moe_impl="dense")
     logits = logits_from_hidden(cfg, params, x, rules, last_only=True)
     assert logits.shape == (B, 1, cfg.padded_vocab)
     assert np.isfinite(np.asarray(logits)).all()

@@ -37,17 +37,17 @@ def test_mla_absorbed_decode_matches_expand():
                         dtype=None)
     pre = {"tokens": toks[:, :S],
            "positions": jnp.broadcast_to(jnp.arange(S), (B, S))}
-    _, cache, _ = forward(cfg, params, pre, rules=rules, cache=cache,
-                          moe_impl="dense")
+    _, cache, _, _ = forward(cfg, params, pre, rules=rules, cache=cache,
+                             moe_impl="dense")
     dec = {"tokens": toks[:, S:S + 1],
            "positions": jnp.full((B, 1), S, jnp.int32)}
-    xd, _, _ = forward(cfg, params, dec, rules=rules, cache=cache,
-                       moe_impl="dense")
+    xd, _, _, _ = forward(cfg, params, dec, rules=rules, cache=cache,
+                          moe_impl="dense")
     la = logits_from_hidden(cfg, params, xd, rules, last_only=True)
     # path B: full forward over S+1 tokens (expand path), take last position
     full = {"tokens": toks,
             "positions": jnp.broadcast_to(jnp.arange(S + 1), (B, S + 1))}
-    xf, _, _ = forward(cfg, params, full, rules=rules, moe_impl="dense")
+    xf, _, _, _ = forward(cfg, params, full, rules=rules, moe_impl="dense")
     lb = logits_from_hidden(cfg, params, xf, rules)[:, -1:, :]
     np.testing.assert_allclose(np.asarray(la, np.float32),
                                np.asarray(lb, np.float32), atol=3e-2)
@@ -63,7 +63,7 @@ def test_decode_matches_full_forward_dense():
         0, cfg.vocab_size, (B, S)))
     full = {"tokens": toks,
             "positions": jnp.broadcast_to(jnp.arange(S), (B, S))}
-    xf, _, _ = forward(cfg, params, full, rules=rules, moe_impl="dense")
+    xf, _, _, _ = forward(cfg, params, full, rules=rules, moe_impl="dense")
     lf = logits_from_hidden(cfg, params, xf, rules)
 
     cache = init_params(cache_specs(cfg, B, S), jax.random.PRNGKey(1),
@@ -72,8 +72,8 @@ def test_decode_matches_full_forward_dense():
     for t in range(S):
         b = {"tokens": toks[:, t:t + 1],
              "positions": jnp.full((B, 1), t, jnp.int32)}
-        xd, cache, _ = forward(cfg, params, b, rules=rules, cache=cache,
-                               moe_impl="dense")
+        xd, cache, _, _ = forward(cfg, params, b, rules=rules, cache=cache,
+                                  moe_impl="dense")
         logits_steps.append(
             logits_from_hidden(cfg, params, xd, rules, last_only=True))
     ld = jnp.concatenate(logits_steps, axis=1)
@@ -90,7 +90,7 @@ def test_rwkv_decode_matches_full_forward():
         0, cfg.vocab_size, (B, S)))
     full = {"tokens": toks,
             "positions": jnp.broadcast_to(jnp.arange(S), (B, S))}
-    xf, _, _ = forward(cfg, params, full, rules=rules, moe_impl="dense")
+    xf, _, _, _ = forward(cfg, params, full, rules=rules, moe_impl="dense")
     lf = logits_from_hidden(cfg, params, xf, rules)
 
     cache = init_params(cache_specs(cfg, B, S), jax.random.PRNGKey(1),
@@ -99,8 +99,8 @@ def test_rwkv_decode_matches_full_forward():
     for t in range(S):
         b = {"tokens": toks[:, t:t + 1],
              "positions": jnp.full((B, 1), t, jnp.int32)}
-        xd, cache, _ = forward(cfg, params, b, rules=rules, cache=cache,
-                               moe_impl="dense")
+        xd, cache, _, _ = forward(cfg, params, b, rules=rules, cache=cache,
+                                  moe_impl="dense")
         outs.append(logits_from_hidden(cfg, params, xd, rules,
                                        last_only=True))
     ld = jnp.concatenate(outs, axis=1)
@@ -169,6 +169,9 @@ def _write_path_cfg(name, attn_impl):
         cfg = dataclasses.replace(cfg, num_layers=4, num_kv_heads=2)
     if name == "jamba-1.5-large-398b":  # attn + mamba in one scanned unit
         cfg = dataclasses.replace(cfg, num_layers=16)
+    if name in ("moonlight-16b-a3b", "deepseek-v2-236b"):
+        # MLA without / with a query latent: a dense prefix and a unit
+        cfg = dataclasses.replace(cfg, num_layers=4)
     return cfg
 
 
@@ -176,6 +179,7 @@ def _write_path_cfg(name, attn_impl):
     ("granite-3-2b", "xla"), ("deepseek-moe-16b", "xla"),
     ("jamba-1.5-large-398b", "xla"),
     ("granite-3-2b", "pallas_interpret"),   # the flash-decode kernel
+    ("moonlight-16b-a3b", "xla"), ("deepseek-v2-236b", "xla"),   # MLA
 ])
 def test_decode_rows_write_matches_mask_write(name, attn_impl):
     """Six decode steps from a prefilled cache, ragged per-slot positions
@@ -196,13 +200,13 @@ def test_decode_rows_write_matches_mask_write(name, attn_impl):
                         dtype=None)
     pre = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab_size, (B, P))),
            "positions": jnp.broadcast_to(jnp.arange(P), (B, P))}
-    _, cache, _ = forward(cfg, params, pre, rules=rules, cache=cache,
-                          moe_impl="dense")
+    _, cache, _, _ = forward(cfg, params, pre, rules=rules, cache=cache,
+                             moe_impl="dense")
 
     def step(r):
         def f(c, b):
-            x, c, _ = forward(cfg, params, b, rules=r, cache=c,
-                              moe_impl="dense")
+            x, c, _, _ = forward(cfg, params, b, rules=r, cache=c,
+                                 moe_impl="dense")
             lg = logits_from_hidden(cfg, params, x, r, last_only=True)
             return x, jnp.argmax(lg[:, -1, :cfg.vocab_size], -1), c
         return jax.jit(f)

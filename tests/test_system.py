@@ -103,7 +103,7 @@ def test_serving_engine_matches_manual_decode():
         for t_eng in req.out_tokens:
             b = {"tokens": jnp.asarray([toks]),
                  "positions": jnp.arange(len(toks))[None, :]}
-            x, _, _ = forward(cfg, params, b, rules=rules, moe_impl="dense")
+            x, _, _, _ = forward(cfg, params, b, rules=rules, moe_impl="dense")
             lg = np.asarray(logits_from_hidden(cfg, params, x, rules)
                             [0, -1, :cfg.vocab_size], np.float32)
             best = int(lg.argmax())
