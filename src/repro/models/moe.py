@@ -20,18 +20,25 @@ Implementations of the routed part (impl arg, chosen by caller):
     baseline. (The hillclimb alternative, core/ep_a2a.py, replaces this
     with a shard_map all-to-all.) Tokens past an expert's capacity are
     dropped.
-  * "ragged" — dropless: the (token, expert) assignments are sorted by
-    held expert and run through one grouped matmul
-    (``jax.lax.ragged_dot``) per projection, so each held expert reads its
-    weights once and computes only the tokens routed to it. The serving
-    path's impl.
+  * "ragged" — dropless, the serving path's impl, in one of two forms
+    chosen by the call's token count T = B * S (:func:`routed_form`):
+    "grouped" sorts the (token, expert) assignments by held expert and
+    runs one grouped matmul (``jax.lax.ragged_dot``) per projection, so
+    each held expert computes only the tokens routed to it; "dense",
+    for T <= ``DENSE_TOKENS`` (a decode step), computes every held
+    expert for every token in three einsums over the held slabs and
+    weighs each result by its routing weight (0 where the token did not
+    choose that expert). A layer scan's slice of the expert stack fuses
+    into an einsum but not into ``ragged_dot``, which takes a copy of
+    each slab; at a few tokens every held expert is hit anyway, so the
+    dense form reads the weights once and its extra FLOPs cost little.
 
 The layer also returns its routing, the experts each token chose
 (``sel``), from which the serving steps count the assignments each
 held expert received. Its parts run under the
-name scopes ``moe.route``, ``moe.experts`` (the grouped matmul of
-"ragged"), ``moe.combine`` and ``moe.shared``, which reach the device
-trace.
+name scopes ``moe.route``, ``moe.experts`` (the expert projections of
+"ragged", either form), ``moe.combine`` and ``moe.shared``, which reach
+the device trace.
 
 Aux (load-balance) loss is returned for training.
 """
@@ -48,6 +55,13 @@ GROUP_TOKENS = 4096
 # tokens a "ragged" call dispatches at once: bounds the sorted copy of
 # the assignments, (chunk * top_k, d_model), in a long prefill
 RAGGED_TOKENS = 4096
+# largest T a "ragged" call computes in the dense form. Every held
+# expert then multiplies every token: 2 * T FLOPs per bf16 weight
+# element, T FLOPs per weight byte, so the form stays bound by reading
+# the weights while T is well under v5e's ridge (197 TFLOP/s over
+# 819 GB/s, ~240 FLOP/byte); 64 is a quarter of it and four times a
+# 16-slot decode step
+DENSE_TOKENS = 64
 
 
 def moe_specs(cfg) -> dict:
@@ -190,6 +204,14 @@ def _gshard(cfg, params, x, rules, r: Routing):
     return out.reshape(B, S, D)
 
 
+def _held_weights(local, weights, n):
+    """(T, K) held indices and routing weights -> (T, n) float32: each
+    token's weight on each held expert, 0 where it did not choose it.
+    Choices of no held expert (local == n) one-hot to zeros."""
+    return jnp.sum(jax.nn.one_hot(local, n, dtype=jnp.float32)
+                   * weights[..., None], axis=1)
+
+
 def _dense(cfg, params, x, rules, r: Routing):
     """Oracle: run all held experts on all tokens, weight by gates."""
     n = cfg.moe.held_range[1]
@@ -198,8 +220,7 @@ def _dense(cfg, params, x, rules, r: Routing):
     xt = x.reshape(1, B * S, D)
     h = jnp.broadcast_to(xt[0][None], (n, B * S, D))[None]    # (1,n,T,D)
     y = _expert_ffn(params, h, dt)                            # (1,n,T,D)
-    w = jnp.sum(jax.nn.one_hot(r.local, n, dtype=jnp.float32)
-                * r.weights[..., None], axis=1)[None]         # (1,T,n)
+    w = _held_weights(r.local, r.weights, n)[None]            # (1,T,n)
     return jnp.einsum("gte,getd->gtd", w.astype(dt), y).reshape(B, S, D)
 
 
@@ -230,12 +251,37 @@ def _grouped(params, xc, local, weights, n, dt):
                           preferred_element_type=jnp.float32).astype(dt)
 
 
+def _every_held(params, xt, local, weights, n, dt):
+    """xt (T, D) through every held expert, dropless: local / weights
+    (T, K) -> (T, D). Each expert's result is weighed by the routing
+    weight of the token's choice of it, 0 where it was not chosen."""
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("td,edf->etf", xt, params["w_gate"].astype(dt))
+        u = jnp.einsum("td,edf->etf", xt, params["w_up"].astype(dt))
+        y = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
+                       params["w_down"].astype(dt))
+    with jax.named_scope("moe.combine"):
+        w = _held_weights(local, weights, n)                  # (T, n)
+        return jnp.einsum("te,etd->td", w.astype(dt), y,
+                          preferred_element_type=jnp.float32).astype(dt)
+
+
+def routed_form(tokens: int) -> str:
+    """The form a "ragged" call of ``tokens`` tokens takes: "dense"
+    (every held expert on every token) up to ``DENSE_TOKENS``, else
+    "grouped" (``ragged_dot`` over the assignments)."""
+    return "dense" if tokens <= DENSE_TOKENS else "grouped"
+
+
 def _ragged(cfg, params, x, rules, r: Routing):
     n = cfg.moe.held_range[1]
     dt = x.dtype
     B, S, D = x.shape
     T = B * S
     xt = x.reshape(T, D)
+    if routed_form(T) == "dense":
+        return _every_held(params, xt, r.local, r.weights, n,
+                           dt).reshape(B, S, D)
     chunk = RAGGED_TOKENS if T > RAGGED_TOKENS and T % RAGGED_TOKENS == 0 \
         else T
     if chunk == T:

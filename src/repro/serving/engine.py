@@ -37,7 +37,11 @@ host), ``engine.extract`` (the ST payload's extract and readback) and
 runs between the last two.
 
 ``moe_impl`` names the MoE layers' implementation (``models/moe.py``):
-"dense" by default, "ragged" for the dropless grouped matmul. A MoE
+"dense" by default, "ragged" for the dropless impl, whose form follows
+a call's token count (``moe.routed_form``): every held expert on every
+token for the decode step's few, the grouped matmul for a prefill's
+many; :meth:`stats` gives the form of the decode shape and of each
+prefill shape run (``moe_routed_form``). A MoE
 model's steps count the assignments each held expert received from the
 slots that serve a request (the batch's ``live`` rows), layer by layer;
 :meth:`stats` gives them per held expert, summed over layers and every
@@ -60,6 +64,7 @@ import numpy as np
 
 from repro.models import cache_specs
 from repro.models.model import decode_cache_write
+from repro.models.moe import routed_form
 from repro.models.params import is_spec
 from repro.runtime import spans
 from repro.train.steps import make_decode_sample_step, make_prefill_sample_step
@@ -114,6 +119,7 @@ class ServingEngine:
         self.steps = 0
         self.tokens_generated = 0
         self.moe_impl = moe_impl
+        self._prefill_lens = set()      # prompt lengths prefilled
         # per held expert, summed over layers and calls (MoE models)
         self.moe_held_assignments = None
         if cfg.moe is not None:
@@ -212,6 +218,7 @@ class ServingEngine:
                 ids, new_cache, *routing = self._prefill_sample(
                     self.params, batch)
                 self.prefill_dispatches += 1
+                self._prefill_lens.add(L)
             # merge ONLY the group's cache rows (other slots keep
             # theirs). prefix-layer leaves are (B, ...); scanned-unit
             # leaves carry a leading layer axis (L, B, ...), so batch is
@@ -367,6 +374,11 @@ class ServingEngine:
              "decode_cache_write": self.decode_cache_write}
         if self.moe_held_assignments is not None:
             d["moe_impl"] = self.moe_impl
+            if self.moe_impl == "ragged":
+                d["moe_routed_form"] = {
+                    "decode": routed_form(self.B),
+                    "prefill": {L: routed_form(self.B * L)
+                                for L in sorted(self._prefill_lens)}}
             d["moe_held_assignments"] = self.moe_held_assignments.tolist()
         if self._router is not None:
             d["st"] = self._router.stats()

@@ -20,6 +20,7 @@ if ROOT not in sys.path:
 from repro.configs import get_config  # noqa: E402
 from repro.models import (cache_specs, forward, init_params,  # noqa: E402
                           logits_from_hidden, model_specs)
+from repro.models import moe as moe_mod  # noqa: E402
 from repro.models.moe import moe, moe_specs, route  # noqa: E402
 from repro.sharding.rules import make_rules  # noqa: E402
 
@@ -182,18 +183,30 @@ def _moe_cfg(held=None, top_k=2):
         cfg.moe, top_k=top_k, held=held))
 
 
-@pytest.mark.parametrize("routing", ["uniform", "skewed"])
-@pytest.mark.parametrize("T", [6, 4096 * 2])     # one chunk, two chunks
-def test_ragged_matches_the_dense_oracle(routing, T):
-    """The dropless grouped matmul gives the oracle's result for every
-    token, whether the tokens spread over the held experts or one held
-    expert takes most of them (far past any capacity a dropping impl
-    would give it); both impls choose the same experts."""
+@pytest.mark.parametrize("routing", ["uniform", "skewed", "idle"])
+@pytest.mark.parametrize("T,form", [
+    pytest.param(6, "dense", id="6"),
+    pytest.param(6, "grouped", id="6-grouped"),
+    pytest.param(4096 * 2, "grouped", id="8192"),     # two chunks
+])
+def test_ragged_matches_the_dense_oracle(routing, T, form, monkeypatch):
+    """Both forms of the dropless impl give the oracle's result for
+    every token, whether the tokens spread over the held experts, one
+    held expert takes most of them (far past any capacity a dropping
+    impl would give it), or one held expert takes none; most choices
+    fall on the 6 experts not held. Both impls choose the same
+    experts. The grouped form runs at a few tokens too, with the dense
+    form's limit lowered below them."""
+    if form == "grouped" and moe_mod.routed_form(T) == "dense":
+        monkeypatch.setattr(moe_mod, "DENSE_TOKENS", 0)
+    assert moe_mod.routed_form(T) == form
     cfg = _moe_cfg(held=(2, 2))
     rules = make_rules(cfg, None, None)
     params = init_params(moe_specs(cfg), jax.random.PRNGKey(0))
     if routing == "skewed":              # expert 2 tops nearly every token
         params["router_bias"] = jnp.zeros(8).at[2].set(5.0)
+    if routing == "idle":                # held expert 3 tops no token
+        params["router_bias"] = jnp.zeros(8).at[2].set(0.5).at[3].set(-5.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, T, cfg.d_model))
     f = jax.jit(lambda p, x, impl: moe(cfg, p, x, rules, impl=impl),
                 static_argnums=2)
@@ -205,6 +218,37 @@ def test_ragged_matches_the_dense_oracle(routing, T):
     assert sel.shape == (1, T, 2) and (sel == np.asarray(sd)).all()
     if routing == "skewed":
         assert int((sel == 2).sum()) > 0.9 * T
+    if routing == "idle":
+        assert not (sel == 3).any() and (sel == 2).any()
+    assert ((sel < 2) | (sel >= 4)).any()
+
+
+def test_routed_form_follows_the_token_count():
+    """A 16-slot decode step (16 tokens) takes the dense form, a 16 x
+    256 prefill the grouped one, and the limit lies between."""
+    assert moe_mod.routed_form(16) == "dense"
+    assert moe_mod.routed_form(16 * 256) == "grouped"
+    assert moe_mod.routed_form(moe_mod.DENSE_TOKENS) == "dense"
+    assert moe_mod.routed_form(moe_mod.DENSE_TOKENS + 1) == "grouped"
+
+
+def test_the_engine_reports_the_routed_form_of_each_step_kind():
+    """``stats()`` gives the form of the 16-slot engine's decode shape
+    and of each prefill shape it ran: dense for the decode, grouped for
+    the 16 x 256 prefill."""
+    from repro.serving import Request, ServingEngine
+    c = tiny_file()
+    mcfg, params = program(c)
+    eng = ServingEngine(mcfg, params, make_rules(mcfg, None, None),
+                        batch_slots=16, max_len=272, moe_impl="ragged")
+    rng = np.random.RandomState(6)
+    eng.submit(Request(prompt=rng.randint(1, 512, 256).astype(np.int32),
+                       max_new_tokens=2))
+    eng.run_until_drained()
+    st = eng.stats()
+    assert st["moe_impl"] == "ragged" and st["decode_steps"] == 1
+    assert st["moe_routed_form"] == {"decode": "dense",
+                                     "prefill": {256: "grouped"}}
 
 
 def test_shares_add_up_to_the_uncut_layer():

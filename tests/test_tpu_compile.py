@@ -1,15 +1,18 @@
 """Compiles for a described TPU v5e (no chip attached): the fused
 executor's Pallas counter bump at the Faces counter shape, alone on one
-chip and inside ``shard_map`` over a 2x2 mesh, and the whole fused Faces
+chip and inside ``shard_map`` over a 2x2 mesh, the whole fused Faces
 program on that mesh with the Pallas backend selected by the mesh's
-platform. What the chip's compiler refuses (tiling, VMEM, partitioning)
-fails here at no chip time.
+platform, and a layer scan of the "ragged" MoE layer at Moonlight's
+widths in each of its forms. What the chip's compiler refuses (tiling,
+VMEM, partitioning) fails here at no chip time.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only the process that runs these tests loads the
 TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,3 +95,59 @@ def test_fused_faces_program_compiles_2x2(mesh_2x2):
     compiled = jfn.lower(*args).compile()
     assert _has_kernel(compiled)
     assert "collective-permute" in compiled.as_text()
+
+
+def _unfused_results(text):
+    """The result type of every instruction of a compiled module that
+    lies outside a fusion's body: what the program materialises."""
+    bodies = set(re.findall(r"kind=k\w+, calls=(%[\w.\-]+)", text))
+    out, outside = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            outside = head.group(1) not in bodies
+        m = re.match(r"\s+(?:ROOT )?%\S+ = (\S+?)(?:\{\S*\})? [\w\-]+\(",
+                     line)
+        if outside and m:
+            out.append(m.group(1))
+    return out
+
+
+@pytest.mark.parametrize("tokens,form", [(16, "dense"), (128, "grouped")])
+def test_ragged_moe_scan_compiles_by_form(topo, tokens, form):
+    """A 2-layer scan of the "ragged" MoE layer at Moonlight's widths (8
+    held experts of 2048 x 1408, top-6 of 64): a 16-slot decode step's
+    16 tokens take the dense form, whose einsums read each layer's
+    expert slabs from the stack inside the dot fusions, with no
+    ``ragged-dot`` and no materialised slab; past the dense form's limit
+    the grouped ``ragged-dot`` runs, on a sliced-out copy of each slab."""
+    from repro.configs import get_config
+    from repro.models import moe as moe_mod
+    from repro.models.params import is_spec
+    from repro.sharding.rules import make_rules
+    cfg = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           held=(0, 8)))
+    assert (cfg.d_model, cfg.moe.expert_ff, cfg.moe.top_k) == (2048, 1408, 6)
+    rules = make_rules(cfg, None, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((2,) + tuple(s.shape), jnp.bfloat16,
+                                       sharding=one),
+        moe_mod.moe_specs(cfg), is_leaf=is_spec)
+    x = jax.ShapeDtypeStruct((tokens, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=one)
+
+    def layers(p, x):
+        def body(x, lp):
+            return moe_mod.moe(cfg, lp, x, rules, impl="ragged")[0], None
+        return jax.lax.scan(body, x, p)[0]
+
+    text = jax.jit(layers).lower(params, x).compile().as_text()
+    slabs = [t for t in _unfused_results(text)
+             if re.fullmatch(r"bf16\[8,(2048,1408|1408,2048)\]", t)]
+    assert moe_mod.routed_form(tokens) == form
+    if form == "dense":
+        assert "ragged-dot" not in text and not slabs
+    else:
+        assert "ragged-dot" in text and len(slabs) == 3
